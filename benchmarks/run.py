@@ -36,6 +36,8 @@ def main() -> None:
                          "committed BENCH_*.json baselines use this)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     from . import (app_loops, applicability, group_agg, ingest,
                    logical_reads, roofline_bench, scalability, serve_agg,
                    tpch_loops, workload_loops)

@@ -307,27 +307,32 @@ _ORDER_INSENSITIVE_KINDS = ("sum", "prod", "min", "max", "arg_group")
 
 
 def _sortfree_eligible(call: AggCall, agg: CustomAggregate, mode: str,
-                       bound) -> bool:
+                       bound, rows: int) -> bool:
     """True when the grouped call may skip the group sort entirely: a
     dense bound is declared (the hash slot table is bucket-sized), the
     call is order-insensitive (no Eq.-6 ordering, no sort keys), the
     physical mode is set-oriented (the segmented scan IS sequential
-    semantics), and every recognized update folds with a commutative
-    merge."""
+    semantics), every recognized update folds with a commutative merge,
+    and — in fused mode — the kernel's unsorted grid over ``rows`` rows
+    per launch does not dwarf the sorted one (``sortfree_grid_fits``)."""
+    from repro.relational.engine import sortfree_grid_fits
     from repro.relational.keyslot import sortfree_enabled
     return (bound is not None and sortfree_enabled()
             and not call.ordered and not call.sort_keys
             and mode in ("fused", "recognized")
             and agg.recognized is not None
             and all(u.kind in _ORDER_INSENSITIVE_KINDS
-                    for u in agg.recognized))
+                    for u in agg.recognized)
+            and (mode != "fused"
+                 or sortfree_grid_fits(_segagg_backend(), rows, bound + 1)))
 
 
-def sortfree_call_route(call: AggCall, bound) -> bool:
+def sortfree_call_route(call: AggCall, bound, rows: int) -> bool:
     """Would this grouped AggCall take the (global-slot) sort-free route
-    for the given validated bound?  Serving-layer entry point: the
-    dispatcher below makes the same decision inline; a cache that wants
-    to pre-build the slot table must predict it without executing."""
+    for the given validated bound, over ``rows`` rows per kernel launch?
+    Serving-layer entry point: the dispatcher below makes the same
+    decision inline; a cache that wants to pre-build the slot table must
+    predict it without executing."""
     if not call.group_keys:
         return False
     agg: CustomAggregate = call.aggregate
@@ -335,7 +340,7 @@ def sortfree_call_route(call: AggCall, bound) -> bool:
         mode = _resolve_grouped_mode(call, agg)
     except ValueError:
         return False
-    return _sortfree_eligible(call, agg, mode, bound)
+    return _sortfree_eligible(call, agg, mode, bound, rows)
 
 
 def grouped_agg_call(call: AggCall, catalog, env,
@@ -363,11 +368,10 @@ def grouped_agg_call(call: AggCall, catalog, env,
         else t.group_bound
     nsegments, bound = resolve_group_bound(declared, t.capacity)
     # a provide_slots scope carrying this call's slot table beats the
-    # per-shard launcher: the cached assignment is global and stable
-    # across calls, so the segment ops use it directly under GSPMD
-    if (shard_route is not None and bound is not None
-            and provided_slots(tuple(call.group_keys), bound) is not None):
-        shard_route = None
+    # per-shard slotting launcher: the cached assignment is global and
+    # stable across calls, so each shard aggregates onto it directly
+    cached = (bound is not None and provided_slots(tuple(call.group_keys),
+                                                   bound) is not None)
     cap = t.capacity
     mode = _resolve_grouped_mode(call, agg)
 
@@ -382,9 +386,11 @@ def grouped_agg_call(call: AggCall, catalog, env,
             outer_vals[name] = eval_expr(e, env)
     _default_missing_fields(agg, env, outer_vals, var_dtypes)
 
-    sortfree = _sortfree_eligible(call, agg, mode, bound)
+    from repro.launch.sharded_agg import launch_rows
+    sortfree = _sortfree_eligible(call, agg, mode, bound,
+                                  launch_rows(cap, shard_route))
     updates_split = None
-    if sortfree and shard_route is not None:
+    if sortfree and shard_route is not None and not cached:
         # sharded sort-free assigns slots per shard inside the launcher —
         # only viable when the WHOLE aggregate lowers to the kernel pass
         # (jnp-routed leftovers would need global segment ids), arg
@@ -405,7 +411,7 @@ def grouped_agg_call(call: AggCall, catalog, env,
     cols: dict[str, jax.Array] = {}
     if sortfree:
         st, m = t, t.mask()
-        if shard_route is not None:
+        if shard_route is not None and not cached:
             out, (rep, out_valid, unplaced) = _grouped_fused(
                 agg, rows, outer_vals, m, None, nsegments,
                 backend=_segagg_backend(),
@@ -421,6 +427,7 @@ def grouped_agg_call(call: AggCall, catalog, env,
                 out = _grouped_fused(agg, rows, outer_vals, m, seg,
                                      nsegments, backend=_segagg_backend(),
                                      require_kernel=call.mode == "fused",
+                                     shard_route=shard_route,
                                      layout="unsorted")
             else:
                 out = _grouped_recognized(agg, rows, outer_vals, m, seg,
@@ -702,7 +709,7 @@ def _grouped_fused(agg, rows, outer_vals, valid, seg, num_segments, backend="aut
                 jnp.stack(masks, axis=1), num_segments, mesh=shard_route[0],
                 axis=shard_route[1], backend=backend,
                 moments=kernel_moments, assume_sorted=True,
-                payloads=tuple(payload_specs))
+                payloads=tuple(payload_specs), layout=layout)
             fused, payload_picks = res if payload_specs else (res, ())
         else:
             from repro.reliability import faults as _faults

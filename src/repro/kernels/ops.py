@@ -25,10 +25,8 @@ from .ssd_scan import ssd_scan as _ssd_pallas
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    # a backend that fails to initialize raises here; it is not "no TPU"
+    return jax.default_backend() == "tpu"
 
 
 def want_pallas(default: bool | None = None) -> bool:

@@ -38,11 +38,23 @@ declared, which shrinks both the ``seg_tiles`` grid term
 (``launched_grid_steps``) and the (C, 4, num_segments) output tensor
 (``moment_tensor_bytes``) from row-capacity-sized to group-count-sized.
 
+The step→block maps are scalar-prefetched into SMEM (two int32 per step,
+1 MiB on v5e), so one launch holds at most ``MAX_PREFETCH_STEPS`` steps.
+A longer sorted input is split into row ranges, one ``pallas_call``
+each; every range accumulates into the previous range's output (aliased
+in place), which is the same sum/count-add, min/max-extremize merge the
+sharded path applies across devices.
+
 Grid (unpruned fallback, ``prune=False``): (num_seg_tiles, num_row_blocks)
-with row blocks iterating fastest.  Block shapes in both layouts:
-  vals  (BLOCK_ROWS, C)  f32          segs  (BLOCK_ROWS, 1) i32
-  valid (BLOCK_ROWS, C)  i32
+with row blocks iterating fastest.  Operands are lane-dense — rows run
+along the 128-wide lane axis — because a (N, 1) column would be padded
+to 128 lanes in HBM (128× its size) before the kernel could read it.
+Block shapes in both layouts:
+  vals  (C, BLOCK_ROWS)  f32          segs  (1, BLOCK_ROWS) i32
+  valid (C, BLOCK_ROWS)  f32 (0/1)
   out   (4*C, BLOCK_SEGS)  row layout [4*c + m] with m = sum,count,min,max
+Inside the kernel one small transpose turns each row block into
+per-row columns for the (BLOCK_ROWS × BLOCK_SEGS) membership mask.
 
 Execution backends (``fused_segment_agg``):
   * ``pallas``    — compiled kernel (real TPU).
@@ -101,6 +113,10 @@ def index_moment_ok(n: int, block_rows: int = 256) -> bool:
 #: TPU vector lane width — segment tiles are sized in multiples of it so
 #: the membership-mask reduce never issues ragged lanes
 LANE = 128
+
+#: steps one band-pruned launch may hold: its two int32 step→block maps
+#: are scalar-prefetched into SMEM (1 MiB on v5e; 2^16 steps use half)
+MAX_PREFETCH_STEPS = 1 << 16
 
 
 def default_block_segs(num_segments: int, block_rows: int = 256,
@@ -242,16 +258,24 @@ def _accum_rows(vals_ref, segs_ref, valid_ref, out_ref, seg_tile, row_base, *,
     """Accumulate one row block into the resident output tile ``seg_tile``
     (a traced i32 scalar on the pruned grid, a grid index otherwise).
     ``row_base`` is the global index of the block's first row — the index
-    moments record ``row_base + local_row`` for the attaining row."""
-    vals = vals_ref[...].astype(out_ref.dtype)          # (R, C)
-    segs = segs_ref[...]                                # (R, 1) int32
-    ok = valid_ref[...] != 0                            # (R, C)
+    moments record ``row_base + local_row`` for the attaining row.
 
-    r = vals.shape[0]
+    The lane-dense (1|C, R) blocks are stacked into one f32 slab and
+    transposed once, giving each quantity as an (R, 1) column.  Segment
+    ids enter the slab tile-relative and clipped to [-1, BS], so f32
+    holds them exactly whatever the global id range."""
+    r = segs_ref.shape[1]
     nrows = moment_rows(moments)
-    local = segs - seg_tile * block_segs                # tile-relative ids
+    local = jnp.clip(segs_ref[...] - seg_tile * block_segs, -1,
+                     block_segs).astype(jnp.float32)          # (1, R)
+    slab = [local, vals_ref[...], valid_ref[...]]             # 1 + 2C rows
+    k = 1 + 2 * num_cols
+    if k % 8:
+        slab.append(jnp.zeros((8 - k % 8, r), jnp.float32))
+    cols = jnp.concatenate(slab, axis=0).T                    # (R, 8m)
+
     seg_iota = lax.broadcasted_iota(jnp.int32, (r, block_segs), 1)
-    in_tile = local == seg_iota                         # (R, BS) band mask
+    in_tile = cols[:, 0:1].astype(jnp.int32) == seg_iota      # (R, BS) band
     idxv = None
     if nrows == 6:
         idxv = (row_base + lax.broadcasted_iota(
@@ -260,8 +284,8 @@ def _accum_rows(vals_ref, segs_ref, valid_ref, out_ref, seg_tile, row_base, *,
     for c in range(num_cols):
         ms = moments[c]
         base = nrows * c
-        member = in_tile & ok[:, c:c + 1]
-        vbc = jnp.broadcast_to(vals[:, c:c + 1], (r, block_segs))
+        member = in_tile & (cols[:, 1 + num_cols + c:2 + num_cols + c] != 0)
+        vbc = jnp.broadcast_to(cols[:, 1 + c:2 + c], (r, block_segs))
         if "sum" in ms:
             out_ref[base + 0, :] += jnp.sum(jnp.where(member, vbc, 0),
                                             axis=0)
@@ -302,13 +326,16 @@ def _segment_agg_kernel(vals_ref, segs_ref, valid_ref, out_ref, *,
 
 
 def _segment_agg_kernel_pruned(rowm_ref, tilem_ref, nsteps_ref,
-                               vals_ref, segs_ref, valid_ref, out_ref, *,
-                               block_rows: int, block_segs: int,
+                               vals_ref, segs_ref, valid_ref, prev_ref,
+                               out_ref, *, block_rows: int, block_segs: int,
                                num_cols: int,
                                moments: tuple[tuple[str, ...], ...]):
-    """Band-pruned 1-D grid: step ``s`` works on row block ``rowm[s]`` and
-    segment tile ``tilem[s]`` (scalar-prefetched maps; the BlockSpec index
-    maps read the same arrays, so only intersecting blocks are fetched).
+    """Band-pruned 1-D grid over one row range: step ``s`` works on row
+    block ``rowm[s]`` and segment tile ``tilem[s]`` (scalar-prefetched
+    maps; the BlockSpec index maps read the same arrays, so only
+    intersecting blocks are fetched).  ``prev_ref`` is the output of the
+    previous row range (identity fills before the first), aliased to
+    ``out_ref``: a tile's first visit starts from it, so ranges chain.
     Steps past ``nsteps`` are grid padding — they repeat the last real
     (row_block, seg_tile) pair so no new DMA is issued, and the accumulate
     is gated off."""
@@ -318,7 +345,7 @@ def _segment_agg_kernel_pruned(rowm_ref, tilem_ref, nsteps_ref,
 
     @pl.when((s == 0) | (j != prev_j))    # first visit of this output tile
     def _():
-        _init_out(out_ref, num_cols, block_segs, moments)
+        out_ref[...] = prev_ref[...]
 
     @pl.when(s < nsteps_ref[0])
     def _():
@@ -393,24 +420,60 @@ def full_grid_steps(n: int, num_segments: int, block_rows: int = 256,
     return n_blocks * -(-num_segments // block_segs)
 
 
+def _range_blocks(num_seg_tiles: int) -> int:
+    """Row blocks per band-pruned launch: the largest range whose static
+    grid (range blocks + seg_tiles − 1) fits ``MAX_PREFETCH_STEPS``."""
+    per = MAX_PREFETCH_STEPS - num_seg_tiles + 1
+    if per < 1:
+        raise ValueError(
+            f"{num_seg_tiles} segment tiles exceed the {MAX_PREFETCH_STEPS} "
+            f"grid steps one pruned launch can map — declare a smaller "
+            f"group bound")
+    return per
+
+
 def launched_grid_steps(n: int, num_segments: int, block_rows: int = 256,
                         block_segs: int | None = None,
                         vmem_budget_elems: int = 1 << 19) -> int:
     """Static grid length ``fused_segment_agg`` actually launches for this
     shape: ``row_blocks`` when the segment range fits one tile (pruning is
     skipped — the row walk already is the whole grid), otherwise the
-    band-pruned ``row_blocks + seg_tiles − 1`` (which includes the padding
-    steps past ``pruned_grid_steps``; padding repeats the last real block
-    pair with the accumulate gated off).  This is the number a dense
-    group bound shrinks: ``seg_tiles`` is sized by ``num_segments``, so
-    bounding it by the group count instead of the row capacity cuts the
-    term — benchmarks/CI compare bounded vs capacity-sized launches."""
+    band-pruned ``row_blocks + seg_tiles − 1`` per row range (which
+    includes the padding steps past ``pruned_grid_steps``; padding repeats
+    the last real block pair with the accumulate gated off), summed over
+    the ranges ``MAX_PREFETCH_STEPS`` splits the rows into.  This is the
+    number a dense group bound shrinks: ``seg_tiles`` is sized by
+    ``num_segments``, so bounding it by the group count instead of the
+    row capacity cuts the term — benchmarks/CI compare bounded vs
+    capacity-sized launches."""
     if block_segs is None:
         block_segs = default_block_segs(num_segments, block_rows,
                                         vmem_budget_elems)
     n_blocks = -(-n // block_rows)
     num_seg_tiles = -(-num_segments // block_segs)
-    return n_blocks if num_seg_tiles == 1 else n_blocks + num_seg_tiles - 1
+    if num_seg_tiles == 1:
+        return n_blocks
+    ranges = -(-n_blocks // _range_blocks(num_seg_tiles))
+    return n_blocks + ranges * (num_seg_tiles - 1)
+
+
+#: the sort-free route's cross-product grid may exceed the sorted route's
+#: pruned grid by at most this factor — beyond it the group sort is the
+#: cheaper way to the kernel (not yet measured on the chip; ROADMAP 1.5)
+SORTFREE_GRID_RATIO = 4
+
+
+def sortfree_grid_ok(n: int, num_segments: int,
+                     block_rows: int = 256) -> bool:
+    """Static route rule for the kernel backends: True when the sort-free
+    (``layout='unsorted'``) cross-product grid over ``n`` rows stays
+    within ``SORTFREE_GRID_RATIO`` × the band-pruned sorted grid.  A
+    segment range that fits one tile always passes (both grids are the
+    row walk); high-cardinality grouping — Q18's order keys at SF 10 are
+    8k tiles — fails and takes the sorted route."""
+    return (full_grid_steps(n, num_segments, block_rows)
+            <= SORTFREE_GRID_RATIO
+            * launched_grid_steps(n, num_segments, block_rows))
 
 
 def moment_tensor_bytes(num_cols: int, num_segments: int) -> int:
@@ -458,6 +521,14 @@ def _pad_rows(vals, segs, valid, block: int):
     return vals, segs, valid
 
 
+def resolve_backend(backend: str) -> str:
+    """``'auto'`` → the compiled kernel on TPU, jnp segment ops elsewhere;
+    any other name passes through."""
+    if backend == "auto":
+        return "pallas" if jax.default_backend() == "tpu" else "jnp"
+    return backend
+
+
 def _normalize(vals: jax.Array, valid: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Lift (N,)/(N,C) vals and valid to matching (N, C)."""
     if vals.ndim == 1:
@@ -486,16 +557,19 @@ def _segment_agg_pallas(vals: jax.Array, segs: jax.Array, valid: jax.Array,
     nrows = moment_rows(moments)
     vals, segs, valid = _pad_rows(vals, segs, valid, block_rows)
     n_p = vals.shape[0]
-    segs2 = segs.astype(jnp.int32).reshape(n_p, 1)
-    valid2 = valid.astype(jnp.int32)
-    vals2 = vals.astype(jnp.float32)
+    segs = segs.astype(jnp.int32)
+    # lane-dense operands: rows along lanes (see the module docstring)
+    segs2 = segs.reshape(1, n_p)
+    vals2 = vals.astype(jnp.float32).T
+    valid2 = valid.astype(jnp.float32).T
 
     num_seg_tiles = -(-num_segments // block_segs)
     s_pad = num_seg_tiles * block_segs
     n_blocks = n_p // block_rows
     if num_seg_tiles == 1:
         prune = False       # single tile: the cross product IS the row walk
-    out_shape = jax.ShapeDtypeStruct((nrows * num_cols, s_pad), jnp.float32)
+    out_rows = nrows * num_cols
+    out_shape = jax.ShapeDtypeStruct((out_rows, s_pad), jnp.float32)
 
     if not prune:
         out = pl.pallas_call(
@@ -505,49 +579,47 @@ def _segment_agg_pallas(vals: jax.Array, segs: jax.Array, valid: jax.Array,
             out_shape=out_shape,
             grid=(num_seg_tiles, n_blocks),
             in_specs=[
-                pl.BlockSpec((block_rows, num_cols), lambda j, i: (i, 0)),
-                pl.BlockSpec((block_rows, 1), lambda j, i: (i, 0)),
-                pl.BlockSpec((block_rows, num_cols), lambda j, i: (i, 0)),
+                pl.BlockSpec((num_cols, block_rows), lambda j, i: (0, i)),
+                pl.BlockSpec((1, block_rows), lambda j, i: (0, i)),
+                pl.BlockSpec((num_cols, block_rows), lambda j, i: (0, i)),
             ],
-            out_specs=pl.BlockSpec((nrows * num_cols, block_segs),
+            out_specs=pl.BlockSpec((out_rows, block_segs),
                                    lambda j, i: (0, j)),
             interpret=interpret,
         )(vals2, segs2, valid2)
         return out[:, :num_segments].reshape(num_cols, nrows, num_segments)
 
-    grid_len = n_blocks + num_seg_tiles - 1
-    rowm, tilem, nsteps = _band_maps(segs.astype(jnp.int32), n_blocks,
-                                     block_rows, block_segs, num_seg_tiles,
-                                     grid_len)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(grid_len,),
-        in_specs=[
-            pl.BlockSpec((block_rows, num_cols),
-                         lambda s, rm, tm, ns: (rm[s], 0)),
-            pl.BlockSpec((block_rows, 1),
-                         lambda s, rm, tm, ns: (rm[s], 0)),
-            pl.BlockSpec((block_rows, num_cols),
-                         lambda s, rm, tm, ns: (rm[s], 0)),
-        ],
-        out_specs=pl.BlockSpec((nrows * num_cols, block_segs),
-                               lambda s, rm, tm, ns: (0, tm[s])),
-    )
-    out = pl.pallas_call(
-        functools.partial(_segment_agg_kernel_pruned, block_rows=block_rows,
-                          block_segs=block_segs, num_cols=num_cols,
-                          moments=moments),
-        out_shape=out_shape,
-        grid_spec=grid_spec,
-        interpret=interpret,
-    )(rowm, tilem, nsteps.reshape(1), vals2, segs2, valid2)
-
-    # tiles no row-block band touches were never visited: their blocks hold
-    # uninitialized memory, so fill them with the moment identities
-    visited = jnp.zeros((num_seg_tiles,), bool).at[tilem].set(True)
-    fill = jnp.array(_row_fills(moments), jnp.float32)
-    out = jnp.where(jnp.repeat(visited, block_segs)[None, :], out,
-                    fill[:, None])
+    # one launch per row range whose step maps fit SMEM; tiles no band
+    # touches keep the identity fills the first range starts from
+    kernel = functools.partial(_segment_agg_kernel_pruned,
+                               block_rows=block_rows, block_segs=block_segs,
+                               num_cols=num_cols, moments=moments)
+    row_spec = pl.BlockSpec((num_cols, block_rows),
+                            lambda s, rm, tm, ns: (0, rm[s]))
+    tile_spec = pl.BlockSpec((out_rows, block_segs),
+                             lambda s, rm, tm, ns: (0, tm[s]))
+    out = jnp.broadcast_to(
+        jnp.array(_row_fills(moments), jnp.float32)[:, None], out_shape.shape)
+    per = _range_blocks(num_seg_tiles)
+    for b0 in range(0, n_blocks, per):
+        nb = min(per, n_blocks - b0)
+        grid_len = nb + num_seg_tiles - 1
+        rowm, tilem, nsteps = _band_maps(
+            segs[b0 * block_rows:(b0 + nb) * block_rows], nb, block_rows,
+            block_segs, num_seg_tiles, grid_len)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(grid_len,),
+            in_specs=[row_spec,
+                      pl.BlockSpec((1, block_rows),
+                                   lambda s, rm, tm, ns: (0, rm[s])),
+                      row_spec, tile_spec],
+            out_specs=tile_spec,
+        )
+        out = pl.pallas_call(
+            kernel, out_shape=out_shape, grid_spec=grid_spec,
+            input_output_aliases={6: 0}, interpret=interpret,
+        )(rowm + b0, tilem, nsteps.reshape(1), vals2, segs2, valid2, out)
 
     if check_sorted:
         # pruning is only meaning-preserving on sorted segs; poison (rather
@@ -758,8 +830,7 @@ def fused_segment_agg(vals: jax.Array, segs: jax.Array, valid: jax.Array,
             f"index moments accumulate f32 row indices, exact only "
             f"below 2^24 (padded) rows; got {vals.shape[0]} — split the "
             f"input or use the exact jnp arg path")
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "jnp"
+    backend = resolve_backend(backend)
     if backend == "jnp":
         return _segment_agg_jnp(vals, segs, valid, num_segments, moments,
                                 sorted_segs=layout == "sorted")

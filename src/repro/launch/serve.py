@@ -126,6 +126,8 @@ def main() -> None:
     args = ap.parse_args()
     if args.requests is None:
         args.requests = 8 if args.workload == "lm" else 1000
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     if args.workload == "lm":
         _serve_lm(args)
     else:

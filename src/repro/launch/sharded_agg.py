@@ -28,9 +28,10 @@ the grouped executors (``core/executors.py`` grouped ``AggCall`` dispatch,
 ``relational/engine.py`` ``GroupAgg``) send such tables through the
 sharded entry with no caller changes — ``Table.shard_rows(mesh, axis)`` is
 all a caller does.  Under tracing, arrays carry no committed sharding, so
-jitted callers keep the single-device kernel (XLA's partitioner still
-shards the surrounding program).  ``REPRO_SEGAGG_SHARDED=off`` disables
-routing.
+a caller that jits over row-sharded tables declares the split with
+``traced_row_sharding`` (the serving layer does): a Mosaic kernel cannot
+be partitioned by GSPMD and must run under ``shard_map``.
+``REPRO_SEGAGG_SHARDED=off`` disables routing.
 
 Rows arrive sorted by segment (the grouped executors sort to derive
 segment ids), so every contiguous row shard is itself sorted — the band
@@ -69,12 +70,13 @@ O(num_segments)-per-shard merge paths above with no changes here.
 """
 from __future__ import annotations
 
+import threading
+from contextlib import contextmanager
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -85,16 +87,41 @@ from repro.kernels.segment_agg import (ARGMAX_ROW, ARGMIN_ROW, MOMENTS,
                                        _normalize, _pad_rows, _row_fills,
                                        _validate_sorted, fused_segment_agg,
                                        has_index_moments, index_moment_ok,
-                                       moment_rows, normalize_moments)
+                                       moment_rows, normalize_moments,
+                                       resolve_backend)
+
+
+_TL = threading.local()
+
+
+@contextmanager
+def traced_row_sharding(route: Optional[tuple[Mesh, str]]):
+    """Declare, for the traces run in this thread's dynamic extent, that
+    the traced row arrays reaching the grouped executors are split as
+    ``route`` = (mesh, axis).  A tracer carries no committed sharding, so
+    a caller that jits over row-sharded tables (``AggServer`` does)
+    states it here; the executors then launch the kernel per shard under
+    ``shard_map``.  A Mosaic kernel cannot be partitioned by GSPMD, so
+    without the scope such a trace fails to compile on TPU."""
+    prev = getattr(_TL, "route", None)
+    _TL.route = route
+    try:
+        yield
+    finally:
+        _TL.route = prev
 
 
 def row_sharded_mesh(*arrays) -> Optional[tuple[Mesh, str]]:
     """(mesh, axis) when any array carries a NamedSharding split over >1
-    device along dim 0; None for tracers, replicated arrays, composite row
-    axes, or when ``REPRO_SEGAGG_SHARDED=off``."""
+    device along dim 0, or — for tracers — the route an enclosing
+    ``traced_row_sharding`` scope declares; None for replicated arrays,
+    composite row axes, or when ``REPRO_SEGAGG_SHARDED=off``."""
     if not flags.enabled("REPRO_SEGAGG_SHARDED"):
         return None
+    traced = getattr(_TL, "route", None)
     for a in arrays:
+        if isinstance(a, jax.core.Tracer) and traced is not None:
+            return traced
         if a is None or isinstance(a, jax.core.Tracer):
             continue
         sh = getattr(a, "sharding", None)
@@ -111,6 +138,15 @@ def row_sharded_mesh(*arrays) -> Optional[tuple[Mesh, str]]:
         if sh.mesh.shape[ax] > 1:
             return sh.mesh, ax
     return None
+
+
+def launch_rows(n: int, route: Optional[tuple[Mesh, str]]) -> int:
+    """Rows one kernel launch walks: the shard's slice under ``route``
+    (rows pad to a multiple of the shard count), all ``n`` without."""
+    if route is None:
+        return n
+    mesh, axis = route
+    return -(-n // mesh.shape[axis])
 
 
 def _merge_moments(local: jax.Array, axis_name: str) -> jax.Array:
@@ -186,7 +222,7 @@ def sharded_fused_segment_agg(vals: jax.Array, segs: jax.Array,
                               block_segs: int | None = None,
                               moments=MOMENTS, prune: bool = True,
                               assume_sorted: bool = False,
-                              payloads=()):
+                              payloads=(), layout: str = "sorted"):
     """Row-sharded fused segmented aggregation over ``mesh.shape[axis]``
     devices: each shard runs ``fused_segment_agg`` on its contiguous row
     slice (full segment range), then the (C, R, num_segments) moment
@@ -195,6 +231,9 @@ def sharded_fused_segment_agg(vals: jax.Array, segs: jax.Array,
     [0, 0, +inf, -inf]); rows are padded to a multiple of the shard count
     with invalid rows repeating the last real segment id, so empty shards
     contribute identities and the per-shard pruned grids stay narrow.
+    ``layout='unsorted'`` takes GLOBAL segment ids in any order (a cached
+    slot table); each shard then runs the unsorted kernel layout and the
+    merge is unchanged.
 
     Index moments (``argmin_*``/``argmax_*`` in ``moments``) extend the
     all-reduce algebra with the cross-shard ARG-merge: each shard's local
@@ -232,10 +271,8 @@ def sharded_fused_segment_agg(vals: jax.Array, segs: jax.Array,
 
     # the sorted precondition only matters where band pruning runs — the
     # per-shard kernel backends; the jnp fallback is order-independent
-    resolved = backend
-    if resolved == "auto":
-        resolved = "pallas" if jax.default_backend() == "tpu" else "jnp"
-    check_runtime = _validate_sorted(segs, prune, assume_sorted, resolved)
+    check_runtime = layout == "sorted" and _validate_sorted(
+        segs, prune, assume_sorted, resolve_backend(backend))
 
     vals, segs, valid = _pad_rows(vals, segs, valid, nshards)
     n_p = vals.shape[0]
@@ -262,7 +299,7 @@ def sharded_fused_segment_agg(vals: jax.Array, segs: jax.Array,
                                 block_rows=block_rows,
                                 block_segs=block_segs, backend=backend,
                                 moments=norm_moments, prune=prune,
-                                assume_sorted=True)
+                                assume_sorted=True, layout=layout)
         if not indexed:
             return _merge_moments(out, axis), ()
         sm = lax.psum(out[:, 0], axis)
@@ -302,10 +339,10 @@ def sharded_fused_segment_agg(vals: jax.Array, segs: jax.Array,
 
     out_specs = (P(), tuple(tuple(P() for _ in pvs)
                             for _c, _m, pvs in payloads))
-    out, picks = shard_map(
+    out, picks = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis),) * (3 + len(pv_flat)),
-        out_specs=out_specs, check_rep=False)(vals, segs, valid, *pv_flat)
+        out_specs=out_specs, check_vma=False)(vals, segs, valid, *pv_flat)
     if check_runtime:
         is_sorted = (jnp.all(segs[1:] >= segs[:-1])
                      if segs.shape[0] > 1 else jnp.bool_(True))
@@ -445,10 +482,10 @@ def sharded_fold_batch(vals: jax.Array, segs: jax.Array, valid: jax.Array,
 
     out_specs = (P(), tuple(tuple(P() for _ in pvs)
                             for _c, _m, pvs in payloads))
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis),) * (4 + len(pv_flat)),
-        out_specs=out_specs, check_rep=False)(vals, segs, valid, pos,
+        out_specs=out_specs, check_vma=False)(vals, segs, valid, pos,
                                               *pv_flat)
 
 
@@ -613,8 +650,8 @@ def sharded_sortfree_segment_agg(vals: jax.Array, key_words: jax.Array,
 
     out_specs = (P(), tuple(tuple(P() for _ in pvs)
                             for _c, _m, pvs in payloads), P(), P(), P())
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis),) * (4 + len(pv_flat)),
-        out_specs=out_specs, check_rep=False)(vals, kw, valid, rowm,
+        out_specs=out_specs, check_vma=False)(vals, kw, valid, rowm,
                                               *pv_flat)

@@ -346,7 +346,16 @@ def segment_ids_for(t: Table, keys: tuple[str, ...],
     same = jnp.ones(st.capacity, dtype=bool)
     for k in keys:
         c = st.columns[k]
-        same = same & jnp.concatenate([jnp.array([False]), c[1:] == c[:-1]])
+        eq = c[1:] == c[:-1]
+        if jnp.issubdtype(c.dtype, jnp.floating):
+            # NaN keys group per bit pattern, as the hash-slotted route's
+            # canonical words do (the sort makes equal NaNs adjacent)
+            from .keyslot import canonical_key_words
+            nan = jnp.isnan(c[1:]) & jnp.isnan(c[:-1])
+            for w in canonical_key_words(c):
+                nan = nan & (w[1:] == w[:-1])
+            eq = eq | nan
+        same = same & jnp.concatenate([jnp.array([False]), eq])
     starts = m & ~same
     seg = jnp.cumsum(starts.astype(jnp.int32)) - 1
     overflow = (st.capacity if num_segments is None else num_segments) - 1
@@ -393,6 +402,20 @@ def _groupagg_fused_backend() -> Optional[str]:
     return "pallas" if jax.default_backend() == "tpu" else None
 
 
+def sortfree_grid_fits(backend: Optional[str], rows: int,
+                       num_segments: int) -> bool:
+    """The sort-free route's kernel-grid gate, shared by ``GroupAgg``,
+    grouped ``AggCall`` and the serving layer's slot provisioning: with a
+    kernel backend, the unsorted cross-product grid over ``rows`` rows per
+    launch must stay within ``SORTFREE_GRID_RATIO`` × the sorted pruned
+    grid; jnp segment ops have no grid, so they always pass.  Reads
+    static shapes only."""
+    from repro.kernels.segment_agg import resolve_backend, sortfree_grid_ok
+    if backend in (None, "off") or resolve_backend(backend) == "jnp":
+        return True
+    return sortfree_grid_ok(rows, num_segments)
+
+
 def _group_agg(t: Table, keys: tuple[str, ...],
                aggs: tuple[tuple[str, str, Optional[str]], ...],
                max_groups: Optional[int] = None) -> Table:
@@ -410,18 +433,16 @@ def _group_agg(t: Table, keys: tuple[str, ...],
     # a row-sharded input table (Table.shard_rows) routes the fused pass
     # through the mesh — one kernel launch per row shard, moments
     # all-reduced; detect on the caller-committed columns, pre-sort.  A
-    # provide_slots scope carrying this call's slot table overrides the
-    # launcher: the cached assignment is GLOBAL (stable across calls), so
-    # the segment ops run on it directly and GSPMD partitions the work.
+    # provide_slots scope carrying this call's slot table replaces the
+    # launcher's per-shard slotting: the cached assignment is GLOBAL
+    # (stable across calls), so each shard aggregates onto it directly.
     shard_route = None
     if backend != "off":
         from repro.launch.sharded_agg import row_sharded_mesh
         shard_route = row_sharded_mesh(*t.columns.values(), t.valid)
-        if (shard_route is not None and bound is not None
-                and provided_slots(keys, bound) is not None):
-            shard_route = None
         if backend is None and shard_route is not None:
             backend = "auto"    # distributed beats per-op even off-TPU
+    cached = bound is not None and provided_slots(keys, bound) is not None
 
     def _fusable(op, col):
         # kernel accumulates in f32: float64 columns keep the exact per-op
@@ -452,15 +473,20 @@ def _group_agg(t: Table, keys: tuple[str, ...],
     # SORT-FREE route: every GroupAgg op is an order-insensitive moment
     # (commutative merge algebra), so whenever a dense bound is declared
     # the hash-slotted segment assignment (relational/keyslot.py) replaces
-    # the group sort outright.  Sharded inputs additionally need every op
-    # on the fused pass — slots are assigned per shard inside the
-    # launcher, so the per-op segment fallbacks have no global ids.
+    # the group sort — unless the kernel's unsorted grid would dwarf the
+    # sorted one (sortfree_grid_fits).  Sharded inputs without cached
+    # slots additionally need every op on the fused pass — slots are
+    # assigned per shard inside the launcher, so the per-op segment
+    # fallbacks have no global ids.
+    from repro.launch.sharded_agg import launch_rows
     sortfree = (bound is not None and sortfree_enabled()
-                and not (shard_route is not None
-                         and (rest_aggs or not fused_aggs)))
+                and not (shard_route is not None and not cached
+                         and (rest_aggs or not fused_aggs))
+                and (not fused_aggs or sortfree_grid_fits(
+                    backend, launch_rows(cap, shard_route), nsegments)))
 
     cols: dict[str, jax.Array] = {}
-    if sortfree and shard_route is not None:
+    if sortfree and shard_route is not None and not cached:
         out, (rep, out_valid, unplaced) = _group_agg_fused(
             t, None, t.mask(), nsegments, fused_aggs, backend,
             shard_route=shard_route, sortfree_keys=keys)
@@ -628,7 +654,7 @@ def _group_agg_fused(st: Table, seg: jax.Array, m: jax.Array,
             vals, seg.astype(jnp.int32), m[:, None], num_segments,
             mesh=shard_route[0], axis=shard_route[1], backend=backend,
             moments=kernel_moments, assume_sorted=True,
-            payloads=tuple(payload_specs))
+            payloads=tuple(payload_specs), layout=layout)
         fused, payload_picks = res if payload_specs else (res, ())
     else:
         fused = fused_segment_agg(vals, seg.astype(jnp.int32), m[:, None],

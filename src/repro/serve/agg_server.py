@@ -63,6 +63,7 @@ from __future__ import annotations
 
 import copy
 import dataclasses
+import logging
 import math
 import threading
 import time
@@ -95,6 +96,8 @@ from .incremental import IncrementalIneligible
 
 __all__ = ["AggServer", "ServeStats", "ServeRequest", "ServeResult",
            "serving_enabled", "guard_enabled"]
+
+_log = logging.getLogger(__name__)
 
 
 def serving_enabled() -> bool:
@@ -277,6 +280,8 @@ class AggServer:
         self._closed = False
         self.stats = ServeStats()
         self.guard_stats = GuardStats()
+        #: keys whose absorbed backend failure was already logged
+        self._logged_failures: set = set()
 
     # -- stats plumbing ----------------------------------------------------
     def _bump(self, name: str, k: int = 1) -> None:
@@ -291,6 +296,18 @@ class AggServer:
     def _stats_copy(self) -> ServeStats:
         with self._stats_lock:
             return copy.copy(self.stats)
+
+    def _log_absorbed(self, key, what: str, exc: Exception) -> None:
+        """Log a backend failure the jnp rung absorbed, with its
+        exception, once per ``key`` — (plan, signature) for launches —
+        so a kernel that never runs is visible beyond the counters."""
+        with self._stats_lock:
+            if key in self._logged_failures:
+                return
+            self._logged_failures.add(key)
+        _log.warning("%s: kernel backend failed; served by the degraded "
+                     "jnp path (backend_failures counts every repeat)",
+                     what, exc_info=exc)
 
     # -- catalog writes: the typed mutation API ----------------------------
     #
@@ -824,6 +841,8 @@ class AggServer:
                 if not self._guard:
                     raise
                 self._gbump("backend_failures")
+                self._log_absorbed((id(res.plan), "ingest_fold"),
+                                   "ingest fold", e)
                 try:
                     res.fold(t, pos, backend="jnp")
                     self._gbump("degraded_launches")
@@ -1059,13 +1078,21 @@ class AggServer:
         return None, ()
 
     @staticmethod
-    def _takes_sortfree(plan: Plan, bound: Optional[int]) -> bool:
+    def _takes_sortfree(plan: Plan, bound: Optional[int], t: Table) -> bool:
         if bound is None or not keyslot.sortfree_enabled():
             return False
+        from repro.launch.sharded_agg import launch_rows, row_sharded_mesh
+        rows = launch_rows(t.capacity,
+                           row_sharded_mesh(*t.columns.values(), t.valid))
         if isinstance(plan, GroupAgg):
-            return True        # every GroupAgg op is an order-insensitive moment
+            # every GroupAgg op is an order-insensitive moment; only the
+            # kernel grid can send it down the sorted route
+            from repro.relational.engine import (_groupagg_fused_backend,
+                                                 sortfree_grid_fits)
+            return sortfree_grid_fits(_groupagg_fused_backend(), rows,
+                                      bound + 1)
         from repro.core.executors import sortfree_call_route
-        return sortfree_call_route(plan, bound)
+        return sortfree_call_route(plan, bound, rows)
 
     def _prepare(self, plan: Plan) -> _PlanEntry:
         ent = self._plans.get(id(plan))
@@ -1096,7 +1123,7 @@ class AggServer:
                 if declared is not None:
                     _, bound = resolve_group_bound(declared, t.capacity)
                     if bound is not None and \
-                            self._takes_sortfree(ent.plan, bound):
+                            self._takes_sortfree(ent.plan, bound, t):
                         ent.keys = keys
                         ent.bound = bound
                         ent.slot_scan = scan
@@ -1239,9 +1266,18 @@ class AggServer:
         return fn
 
     def _build(self, ent: _PlanEntry, psig, nb: int, degraded: bool = False):
+        from repro.launch.sharded_agg import (row_sharded_mesh,
+                                              traced_row_sharding)
         plan = ent.plan
         spec = (ent.keys, ent.bound) if ent.slot_scan is not None else None
         stats = self.stats
+        # the tables arrive as tracers, which carry no sharding: declare
+        # the row split of the scanned tables so the kernel runs per shard
+        route = row_sharded_mesh(*(
+            a for name in self._plan_tables(ent.submitted)
+            if name in self._catalog
+            for a in (*self._catalog[name].columns.values(),
+                      self._catalog[name].valid)))
 
         def run(tables, slots, pvec):
             stats.traces += 1    # Python side effect: counts traces only
@@ -1257,7 +1293,7 @@ class AggServer:
                 with keyslot.provide_slots({spec: slots}):
                     return execute(plan, tables, env)
 
-            with ctx:
+            with ctx, traced_row_sharding(route):
                 if not psig:
                     return one({})
                 return jax.vmap(one)(pvec)
@@ -1282,14 +1318,24 @@ class AggServer:
         return [strip_poison_stamp(o) if isinstance(o, Table) else o
                 for o in outs]
 
-    def _launch_bucket(self, ent: _PlanEntry, psig, plist,
-                       degraded: bool = False):
-        n = len(plist)
+    def _bucket_args(self, ent: _PlanEntry, psig, plist):
+        """(batch bucket, executable arguments) for one request bucket."""
         slots = ()
         if ent.slot_scan is not None:
             got = self._slot_table(ent)   # may grow/disable the bound
             slots = got if got is not None else ()
-        nb = 1 if not psig else 1 << (n - 1).bit_length()
+        if not psig:
+            return 1, (self._catalog, slots, {})
+        nb = 1 << (len(plist) - 1).bit_length()
+        padded = plist + [plist[-1]] * (nb - len(plist))  # pad lanes
+        pvec = {k: jnp.asarray(np.stack([np.asarray(p[k]) for p in padded]))
+                for k, _ in psig}
+        return nb, (self._catalog, slots, pvec)
+
+    def _launch_bucket(self, ent: _PlanEntry, psig, plist,
+                       degraded: bool = False):
+        n = len(plist)
+        nb, args = self._bucket_args(ent, psig, plist)
         fn = self._executable(ent, psig, nb, degraded)
         self._bump("requests", n)
         self._bump("batches")
@@ -1297,15 +1343,26 @@ class AggServer:
             self._gbump("degraded_launches")
         if not degraded:
             faults.fail("backend_exc")
+        out = fn(*args)
         if not psig:
-            out = fn(self._catalog, slots, {})
             return [out] * n
-        padded = plist + [plist[-1]] * (nb - n)   # pad lanes, drop below
-        pvec = {k: jnp.asarray(np.stack([np.asarray(p[k]) for p in padded]))
-                for k, _ in psig}
-        batched = fn(self._catalog, slots, pvec)
-        return [jax.tree_util.tree_map(lambda a, i=i: a[i], batched)
-                for i in range(n)]
+        return [jax.tree_util.tree_map(lambda a, i=i: a[i], out)
+                for i in range(n)]    # padded lanes dropped
+
+    def compiled_text(self, plan: Plan,
+                      params: Optional[Mapping[str, Any]] = None) -> str:
+        """Optimized HLO text of the primary executable serving one
+        ``(plan, params)`` request — what the device runs, so a caller can
+        check which kernels it holds (``tpu_custom_call`` on TPU).
+        Lowers with the launch's own arguments, so an executable that
+        already ran is neither retraced nor recompiled."""
+        params = dict(params or {})
+        with self._lock:
+            ent = self._prepare(plan)
+            psig = self._psig(params)
+            nb, args = self._bucket_args(ent, psig, [params])
+            fn = self._executable(ent, psig, nb)
+        return fn.lower(*args).compile().as_text()
 
     # -- guarded launch ----------------------------------------------------
     def _breaker(self, ent: _PlanEntry, psig) -> CircuitBreaker:
@@ -1351,6 +1408,8 @@ class AggServer:
                     raise BackendFailure(
                         "degraded (jnp) launch failed") from e
                 self._gbump("backend_failures")
+                self._log_absorbed((id(ent.submitted), psig),
+                                   type(ent.submitted).__name__, e)
                 if br.record_failure():
                     self._gbump("breaker_trips")
                 try:

@@ -281,4 +281,8 @@ CORPUS = [
     {"seed": 9, "n": 136, "key_dtypes": ("int32",), "card": 2,
      "invalid_frac": 0.6, "aggs": ("prod", "sum", "argmax"),
      "max_groups": 8},
+    # NaN keys and no bound: the fresh call sorts, and must still group
+    # equal NaN keys as one group, like the hash-slotted route
+    {"seed": 0, "n": 136, "key_dtypes": ("float32",), "card": 2,
+     "nan_keys": True, "aggs": ("sum",)},
 ]

@@ -150,6 +150,37 @@ def test_pruned_vs_unpruned_and_oracle_parity():
     assert executed * 3 < full
 
 
+@pytest.mark.parametrize("moments", [
+    ("sum", "count", "min", "max"),
+    (("min", "argmin_first"), ("max", "argmax_last")),
+])
+def test_pruned_launch_splits_row_ranges(monkeypatch, moments):
+    """A sorted input whose step maps exceed one launch's SMEM budget is
+    split into row ranges chained through the aliased output: the result
+    equals the single-launch and unpruned grids bit for bit, tiles no
+    band touches keep their identities, and ``launched_grid_steps``
+    counts every range's padding."""
+    import importlib
+    sa = importlib.import_module("repro.kernels.segment_agg")
+    segs, vals, valid = _sorted_workload(5003, 900, ncols=2, seed=5)
+    segs = np.where(segs > 700, segs + 150, segs)    # untouched tiles
+    args = (jnp.asarray(vals), jnp.asarray(segs), jnp.asarray(valid), 1100)
+    kw = dict(block_rows=128, block_segs=128, backend="interpret",
+              moments=moments)
+    whole = fused_segment_agg(*args, **kw)
+    un = fused_segment_agg(*args, prune=False, **kw)
+    monkeypatch.setattr(sa, "MAX_PREFETCH_STEPS", 24)   # 9 tiles: 16 blocks
+    sa._segment_agg_pallas.clear_cache()
+    try:
+        split = fused_segment_agg(*args, **kw)
+        steps = sa.launched_grid_steps(5003, 1100, 128, 128)
+    finally:
+        sa._segment_agg_pallas.clear_cache()
+    assert steps == 40 + 3 * 8                       # 3 ranges of ≤16 blocks
+    assert np.array_equal(np.asarray(split), np.asarray(whole))
+    assert np.array_equal(np.asarray(split), np.asarray(un))
+
+
 def test_pruned_grid_steps_acceptance_200k():
     """ISSUE 2 acceptance: a sorted N=200k / S=8192 workload executes at
     most row_blocks + 2·seg_tiles grid steps — vs the row_blocks ×

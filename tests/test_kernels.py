@@ -1,5 +1,7 @@
 """Per-kernel interpret-mode sweeps vs the pure-jnp oracles (shape × dtype
 grids), per the kernel contract in src/repro/kernels/."""
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -31,6 +33,39 @@ def test_segment_agg_sweep(n, nseg, block, dtype):
     tol = 1e-5 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=tol, atol=tol)
+
+
+def test_backend_probe_failure_is_not_read_as_no_tpu(monkeypatch):
+    """A backend that fails to initialize must surface, not silently
+    select the CPU fallback kernels."""
+    from repro.kernels import ops
+
+    def broken():
+        raise RuntimeError("TPU backend failed to initialize")
+
+    monkeypatch.delenv("REPRO_USE_PALLAS", raising=False)
+    monkeypatch.setattr(jax, "default_backend", broken)
+    with pytest.raises(RuntimeError, match="failed to initialize"):
+        ops.want_pallas()
+
+
+def test_compile_cache_directory(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; without it the cache
+    goes to the fixed in-checkout directory."""
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.configure_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        got = compile_cache.configure_compile_cache()
+        assert got == str(compile_cache.CHECKOUT_CACHE)
+        assert jax.config.jax_compilation_cache_dir == got
+        repo_root = Path(__file__).resolve().parents[1]
+        assert compile_cache.CHECKOUT_CACHE.parent == repo_root
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_segment_agg_all_invalid_segment():

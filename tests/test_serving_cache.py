@@ -70,6 +70,19 @@ def test_slot_table_built_exactly_once_across_repeats():
     assert srv.stats.traces == 1
 
 
+def test_compiled_text_reads_the_served_executable():
+    """``compiled_text`` lowers the executable a request runs with that
+    request's own arguments: no retrace, no new executable, no slot
+    rebuild — and the text is the optimized program the device runs."""
+    srv = AggServer({"T": _table()})
+    plan = _plan()
+    srv.execute(plan)
+    txt = srv.compiled_text(plan)
+    assert "HloModule" in txt
+    assert srv.stats.traces == 1 and srv.stats.slot_builds == 1
+    assert srv.describe(plan)["executables"] == 1
+
+
 def test_mutation_rebuilds_slots_once_from_new_version(monkeypatch):
     t = _table()
     srv = AggServer({"T": t})

@@ -137,17 +137,23 @@ plan = GroupAgg(Scan("T", ("k", "v")), ("k",),
                  ("mx", "max", "v")), max_groups=64)
 want = execute(plan, {"T": t}).to_numpy()
 
-launcher_calls = []
+launcher_calls, shard_calls = [], []
 orig = sa.sharded_sortfree_segment_agg
 sa.sharded_sortfree_segment_agg = lambda *a, **k: (launcher_calls.append(1),
                                                    orig(*a, **k))[1]
+orig_fused = sa.sharded_fused_segment_agg
+sa.sharded_fused_segment_agg = lambda *a, **k: (
+    shard_calls.append(k.get("layout")), orig_fused(*a, **k))[1]
 srv = AggServer({"T": t.shard_rows(mesh, "data")})
 outs = [srv.execute(plan) for _ in range(3)]
 # stable cross-call global slot assignment: one build, one trace, and the
-# per-shard launcher never runs — the cached global slots go through GSPMD
+# per-shard slotting launcher never runs — each shard aggregates onto the
+# cached global slots under shard_map (a Mosaic kernel cannot be
+# partitioned by GSPMD)
 assert srv.stats.slot_builds == 1, srv.stats
 assert srv.stats.traces == 1, srv.stats
 assert not launcher_calls, "cached-slot serving must bypass the launcher"
+assert shard_calls == ["unsorted"], shard_calls
 o0 = outs[0].to_numpy()
 for o in outs[1:]:
     on = o.to_numpy()

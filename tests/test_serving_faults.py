@@ -9,6 +9,7 @@ under pytest's sequential runner.
 """
 from __future__ import annotations
 
+import logging
 import os
 import subprocess
 import sys
@@ -197,13 +198,14 @@ def test_slot_stale_unbounded_surfaces_typed():
 # ---------------------------------------------------------------------------
 
 
-def test_backend_failure_degrades_trips_and_recovers():
+def test_backend_failure_degrades_trips_and_recovers(caplog):
     clk = [0.0]
     srv, t, plan, keys, aggs, envs = _fresh(
         CASE_SMALL, breaker_threshold=2, breaker_cooldown_s=10.0,
         breaker_clock=lambda: clk[0])
     with srv:
-        with faults.inject("backend_exc"):
+        with faults.inject("backend_exc"), caplog.at_level(
+                logging.WARNING, logger="repro.serve.agg_server"):
             # every primary launch raises; the ladder serves each request
             # on the degraded jnp executable — callers see only results
             for i in range(3):
@@ -215,6 +217,12 @@ def test_backend_failure_degrades_trips_and_recovers():
         # goes straight to the degraded path without touching the primary
         assert gs.backend_failures == 2
         assert gs.breaker_trips == 1
+        # every absorbed failure is counted; its exception is logged once
+        # per (plan, signature)
+        logged = [r for r in caplog.records
+                  if "degraded jnp path" in r.getMessage()]
+        assert len(logged) == 1
+        assert isinstance(logged[0].exc_info[1], FaultInjected)
         assert srv.describe(plan)["breakers"][()] == "open"
         # faults disarmed + cool-down elapsed: the half-open probe takes
         # the primary again, succeeds, and the breaker closes

@@ -152,7 +152,6 @@ def test_shard_merge_fold_matches_collective_merge(mesh):
     """moment_merge_aggregate under core.aggregate.shard_merge (all-gather
     + ordered fold) == the native psum/pmin/pmax merge — the sharded path
     really is the shard_merge algebra."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.aggregate import shard_merge
@@ -171,8 +170,9 @@ def test_shard_merge_fold_matches_collective_merge(mesh):
     def fold(loc):
         return shard_merge(agg, loc[0], "data")
 
-    folded = shard_map(fold, mesh=mesh, in_specs=(P("data"),),
-                       out_specs=P(), check_rep=False)(jnp.stack(locals_))
+    folded = jax.shard_map(fold, mesh=mesh, in_specs=(P("data"),),
+                           out_specs=P(), check_vma=False)(
+                               jnp.stack(locals_))
     shd = sharded_fused_segment_agg(jnp.asarray(vals), jnp.asarray(segs),
                                     jnp.asarray(valid), 128, mesh=mesh,
                                     axis="data", backend="jnp")

@@ -313,6 +313,38 @@ def test_sortfree_requires_declared_bound(monkeypatch):
     assert len(calls) == 1
 
 
+@pytest.mark.parametrize("fused,sortfree", [("jnp", True),
+                                            ("interpret", False)])
+def test_kernel_grid_rule_picks_sorted_route(monkeypatch, fused, sortfree):
+    """High-cardinality grouping under a kernel backend: the unsorted
+    cross-product grid (9 tiles × 79 row blocks) dwarfs the pruned sorted
+    grid, so GroupAgg and grouped AggCall sort instead — jnp segment ops
+    have no grid and stay sort-free.  Both routes give the same groups."""
+    from repro.kernels.segment_agg import sortfree_grid_ok
+    assert sortfree_grid_ok(4096, 513) and not sortfree_grid_ok(20000, 16385)
+    calls = _slot_spy(monkeypatch)
+    monkeypatch.setenv("REPRO_GROUPAGG_FUSED", fused)
+    monkeypatch.setenv("REPRO_SEGAGG_BACKEND", fused)
+    t = _table(20000, 10000, seed=9)
+    plan = GroupAgg(Scan("T", ("k", "v", "w")), ("k",),
+                    (("s", "sum", "v"), ("mx", "max", "w")),
+                    max_groups=10000)
+    got = _aligned(execute(plan, {"T": t}), "k")
+    from benchmarks.group_agg import _programs
+    prog, env = _programs()["sum_count"]
+    call = _grouped_call(prog, "fused", 10000)
+    got_call = _aligned(execute(call, _ps_catalog(20000, 10000, seed=4),
+                                env), "ps_partkey")
+    assert len(calls) == (2 if sortfree else 0)
+    monkeypatch.setenv("REPRO_GROUPAGG_SORTFREE", "off")
+    want = _aligned(execute(plan, {"T": t}), "k")
+    want_call = _aligned(execute(call, _ps_catalog(20000, 10000, seed=4),
+                                 env), "ps_partkey")
+    for w, g in ((want, got), (want_call, got_call)):
+        for c in w:
+            np.testing.assert_allclose(w[c], g[c], rtol=1e-6, err_msg=c)
+
+
 def test_sortfree_kill_switch(monkeypatch):
     calls = _slot_spy(monkeypatch)
     monkeypatch.setenv("REPRO_GROUPAGG_SORTFREE", "off")
