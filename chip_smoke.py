@@ -199,32 +199,29 @@ def grouped_calls(catalog):
     return out
 
 
-def _call_route(call, rows: int, params: dict) -> tuple[str, bool, int]:
-    """(route, kernel?, launched grid steps) the executors choose."""
-    from repro.core.executors import sortfree_call_route
-    from repro.kernels.segment_agg import (full_grid_steps,
-                                           launched_grid_steps)
-    from repro.relational.group_bound import resolve_group_bound
-    nseg, bound = resolve_group_bound(call.max_groups, rows)
-    kernel = any(u.kind in ("sum", "min", "max", "arg_group")
-                 and np.asarray(params[u.fields[0]]).dtype == np.float32
-                 for u in call.aggregate.recognized)
-    if not kernel:
-        return "jnp-by-dtype", False, 0
-    if sortfree_call_route(call, bound, rows):
-        return "sort-free", True, full_grid_steps(rows, nseg)
-    return "sorted", True, launched_grid_steps(rows, nseg)
+def _route(plan, catalog, params, srv=None):
+    """The route the engine takes for a grouped ``plan``
+    (``engine.grouped_route`` over the abstract input table), served by
+    ``srv`` — with the bound it infers and the slot table it provides,
+    when it does — or run directly."""
+    import dataclasses
+    import jax
+    from repro.relational import execute
+    from repro.relational.engine import grouped_route
+    t = jax.eval_shape(lambda c: execute(plan.child, c, params), catalog)
+    cached = False
+    if srv is not None:
+        d = srv.describe(plan)
+        plan = dataclasses.replace(plan, max_groups=d["max_groups"])
+        cached = d["slot_scan"] is not None
+    return grouped_route(plan, t, params, cached=cached)
 
 
-def _tile_route(srv, tile, rows: int) -> tuple[str, bool, int]:
-    from repro.kernels.segment_agg import (full_grid_steps,
-                                           launched_grid_steps)
-    from repro.relational.group_bound import resolve_group_bound
-    d = srv.describe(tile)
-    nseg, _ = resolve_group_bound(d["max_groups"], rows)
-    if d["slot_scan"] is not None:
-        return "sort-free", True, full_grid_steps(rows, nseg)
-    return "sorted", True, launched_grid_steps(rows, nseg)
+def _print_route(name, route, t_setup, t_serve):
+    print(f"{name}: route={route.name} kernel={route.kernel} "
+          f"launched_grid_steps={route.grid_steps} "
+          f"setup_compile_s={t_setup:.2f} serve_s={t_serve:.3f}",
+          flush=True)
 
 
 def _timed(fn):
@@ -359,14 +356,10 @@ def run_one_chip(args, check, device) -> None:
 
     # grouped Aggify+ loops through the server
     for q, (call, prog, params) in calls.items():
-        rows = (catalog["PARTSUPP"] if q == "Q2"
-                else catalog[call.child.table]).capacity
-        route, kernel, steps = _call_route(call, rows, params)
         out, t_setup = _timed(lambda: srv.execute(call, params))
         again, t_serve = _timed(lambda: srv.execute(call, params))
-        print(f"{q}: route={route} kernel={kernel} "
-              f"launched_grid_steps={steps} setup_compile_s={t_setup:.2f} "
-              f"serve_s={t_serve:.3f}", flush=True)
+        route = _route(call, catalog, params, srv)
+        _print_route(q, route, t_setup, t_serve)
         gk = call.group_keys[0]
         ret = prog.returns[0]
         keys, vals = _result_map(out, gk, ret)
@@ -376,7 +369,7 @@ def run_one_chip(args, check, device) -> None:
         k2, v2 = _result_map(again, gk, ret)
         check(np.array_equal(k2, keys) and np.array_equal(v2, vals),
               f"{q}: cached re-serve is identical")
-        _kernel_text(check, srv, call, params, q, kernel, on_tpu)
+        _kernel_text(check, srv, call, params, q, route.kernel, on_tpu)
         cursor_reference(check, q, prog, catalog, h, (keys, vals), rng)
 
     # concurrent submits: Q2 at two lower bounds coalesces into one
@@ -417,16 +410,13 @@ def run_one_chip(args, check, device) -> None:
     # dashboard tiles, then ingest folded into a resident tile
     for name, tile in tiles().items():
         table = tile.child.table
-        route, kernel, steps = _tile_route(srv, tile,
-                                           catalog[table].capacity)
         out, t_setup = _timed(lambda: srv.execute(tile, {}))
         f = srv.submit(tile, {})
         _o, t_serve = _timed(lambda: f.result(timeout=900))
-        print(f"{name}: route={route} kernel={kernel} "
-              f"launched_grid_steps={steps} setup_compile_s={t_setup:.2f} "
-              f"serve_s={t_serve:.3f}", flush=True)
+        route = _route(tile, catalog, {}, srv)
+        _print_route(name, route, t_setup, t_serve)
         check_tile(check, name, out, h, table, tile.keys[0], tile.aggs)
-        _kernel_text(check, srv, tile, {}, name, kernel, on_tpu)
+        _kernel_text(check, srv, tile, {}, name, route.kernel, on_tpu)
 
     tile = tiles()["tile_rev"]
     srv.serve(ServeRequest(tile, consistency="epoch"))     # seeds residency
@@ -465,19 +455,21 @@ def numpy_whole(q: str, h: dict, prm: dict) -> float:
 
 def run_four_chips(args, check, devices) -> None:
     """LINEITEM row-sharded over a 4-device mesh: a grouped loop and a
-    tile on it through the direct executors (the ``shard_map``
-    launchers) and through ``AggServer``, each equal to the same call on
-    one device and to numpy."""
+    tile on it through the direct executors (one jitted ``execute``,
+    which launches the kernel per shard under ``shard_map``) and through
+    ``AggServer``, each equal to the same call on one device and to
+    numpy."""
     import jax
     from jax.sharding import Mesh
     from repro.relational import execute
     from repro.relational.tpch import gen_tpch
     from repro.serve import AggServer
 
+    on_tpu = devices[0].platform == "tpu"
     t0 = time.perf_counter()
     catalog = gen_tpch(scale=args.scale, seed=args.seed)
     jax.block_until_ready([t.columns for t in catalog.values()])
-    h = _host(catalog)
+    h = _host({n: catalog[n] for n in ("LINEITEM", "ORDERS")})
     mesh = Mesh(np.asarray(devices[:4]), ("data",))
     sharded = dict(catalog)
     sharded["LINEITEM"] = catalog["LINEITEM"].shard_rows(mesh, "data")
@@ -490,7 +482,8 @@ def run_four_chips(args, check, devices) -> None:
     srv = AggServer(sharded)
     calls = grouped_calls(catalog)
     # Q18: the sorted route, pruned launch per shard; tile_ship: the
-    # sort-free route on cached global slots (Q21 shares Q18's path)
+    # sort-free route — per-shard slotting in the direct executor, the
+    # cached global slots in the server (Q21 shares Q18's path)
     call, prog, params = calls["Q18"]
     plans = [("Q18", call, params, prog.returns[0]),
              ("tile_ship", tiles()["tile_ship"], {}, "qty")]
@@ -498,10 +491,20 @@ def run_four_chips(args, check, devices) -> None:
         key = plan.group_keys[0] if hasattr(plan, "group_keys") \
             else plan.keys[0]
         base, t1 = _timed(lambda: one.execute(plan, params))
-        direct, t2 = _timed(lambda: execute(plan, sharded, params))
+
+        t = time.perf_counter()
+        compiled = jax.jit(lambda c, p, plan=plan: execute(plan, c, p)
+                           ).lower(sharded, params).compile()
+        direct, _ = _timed(lambda: compiled(sharded, params))
+        t2 = time.perf_counter() - t
         served, t3 = _timed(lambda: srv.execute(plan, params))
         print(f"{name}: one-device {t1:.2f}s, sharded executor {t2:.2f}s, "
               f"sharded server {t3:.2f}s (each incl. compile)", flush=True)
+        for who, via in (("executor", None), ("server", srv)):
+            r = _route(plan, sharded, params, via)
+            print(f"{name} sharded {who}: route={r.name} kernel={r.kernel} "
+                  f"rows_per_shard={r.rows} "
+                  f"launched_grid_steps={r.grid_steps}", flush=True)
         bk, bv = _result_map(base, key, col)
         order = np.argsort(bk)
         for route, out in (("executor", direct), ("server", served)):
@@ -515,8 +518,12 @@ def run_four_chips(args, check, devices) -> None:
         else:
             present, ref = numpy_grouped(name, h, {})
             _check_groups(check, name, bk, bv, present, ref)
+        if on_tpu:
+            check("tpu_custom_call" in compiled.as_text(),
+                  f"{name} sharded executor: compiled program holds the "
+                  f"kernel (tpu_custom_call)")
         _kernel_text(check, srv, plan, params, f"{name} sharded server",
-                     True, devices[0].platform == "tpu")
+                     True, on_tpu)
     _guard_clean(check, srv, "sharded serving guard")
     _guard_clean(check, one, "one-device serving guard")
     srv.close()

@@ -38,7 +38,7 @@ overrides, and the legacy ``REPRO_SEGAGG_PALLAS=1`` forces the kernel
 """
 from __future__ import annotations
 
-from typing import Any, Mapping, Optional
+from typing import Any, Mapping, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -307,15 +307,17 @@ _ORDER_INSENSITIVE_KINDS = ("sum", "prod", "min", "max", "arg_group")
 
 
 def _sortfree_eligible(call: AggCall, agg: CustomAggregate, mode: str,
-                       bound, rows: int) -> bool:
+                       bound, rows: int, nsegments: int) -> bool:
     """True when the grouped call may skip the group sort entirely: a
     dense bound is declared (the hash slot table is bucket-sized), the
     call is order-insensitive (no Eq.-6 ordering, no sort keys), the
     physical mode is set-oriented (the segmented scan IS sequential
     semantics), every recognized update folds with a commutative merge,
     and — in fused mode — the kernel's unsorted grid over ``rows`` rows
-    per launch does not dwarf the sorted one (``sortfree_grid_fits``)."""
-    from repro.relational.engine import sortfree_grid_fits
+    per launch does not dwarf the sorted one.  The grid rule reads the
+    mode, not the parameters' dtypes, so a plan's route is known before
+    its first request."""
+    from repro.relational.engine import kernel_grid_allows_sortfree
     from repro.relational.keyslot import sortfree_enabled
     return (bound is not None and sortfree_enabled()
             and not call.ordered and not call.sort_keys
@@ -323,44 +325,37 @@ def _sortfree_eligible(call: AggCall, agg: CustomAggregate, mode: str,
             and agg.recognized is not None
             and all(u.kind in _ORDER_INSENSITIVE_KINDS
                     for u in agg.recognized)
-            and (mode != "fused"
-                 or sortfree_grid_fits(_segagg_backend(), rows, bound + 1)))
+            and (mode != "fused" or kernel_grid_allows_sortfree(
+                _segagg_backend(), rows, nsegments)))
 
 
-def sortfree_call_route(call: AggCall, bound, rows: int) -> bool:
-    """Would this grouped AggCall take the (global-slot) sort-free route
-    for the given validated bound, over ``rows`` rows per kernel launch?
-    Serving-layer entry point: the dispatcher below makes the same
-    decision inline; a cache that wants to pre-build the slot table must
-    predict it without executing."""
-    if not call.group_keys:
-        return False
+class _CallSetup(NamedTuple):
+    mode: str
+    nsegments: int
+    bound: Optional[int]
+    shard_route: Optional[tuple]
+    cached: bool
+    rows: dict
+    outer_vals: dict
+    updates_split: Optional[tuple]
+    route: "GroupRoute"
+
+
+def _grouped_call_setup(call: AggCall, t: Table, env,
+                        var_dtypes=None,
+                        cached: Optional[bool] = None) -> _CallSetup:
+    """Every static decision of ``grouped_agg_call`` over its input table
+    ``t``: segment range, row split, parameter bindings, and the route.
+    ``env`` None (the parameters are not known yet) leaves the kernel
+    bit of the route undecided."""
+    from repro.launch.sharded_agg import launch_rows, table_row_split
+    from repro.relational.engine import GroupRoute
+    from repro.relational.group_bound import resolve_group_bound
+    from repro.relational.keyslot import provided_slots
     agg: CustomAggregate = call.aggregate
-    try:
-        mode = _resolve_grouped_mode(call, agg)
-    except ValueError:
-        return False
-    return _sortfree_eligible(call, agg, mode, bound, rows)
-
-
-def grouped_agg_call(call: AggCall, catalog, env,
-                     var_dtypes=None) -> Table:
-    agg: CustomAggregate = call.aggregate
-    t = _engine.execute_for_agg(call.child, catalog, env,
-                                _agg_call_needed(call))
-    # row-sharded input (Table.shard_rows): the fused path runs the kernel
-    # per shard and all-reduces moments; detect BEFORE the sort, on the
-    # columns the caller committed
-    from repro.launch.sharded_agg import row_sharded_mesh
-    shard_route = row_sharded_mesh(*t.columns.values(), t.valid)
-    from repro.relational.engine import segment_ids_for
-    from repro.relational.group_bound import (check_group_overflow,
-                                              poison_overflow,
-                                              resolve_group_bound)
-    from repro.relational.keyslot import (overflow_extended,
-                                          provided_slots,
-                                          slot_segment_ids,
-                                          sortfree_result)
+    # row-split input (Table.shard_rows): the fused path runs the kernel
+    # per shard and all-reduces moments; read BEFORE the sort
+    shard_route = table_row_split(t)
     # dense segment range: AggCall-declared max_groups beats the table
     # hint; every segment tensor below (and the kernel / all-reduce
     # payload) is sized by it instead of the row capacity
@@ -370,8 +365,9 @@ def grouped_agg_call(call: AggCall, catalog, env,
     # a provide_slots scope carrying this call's slot table beats the
     # per-shard slotting launcher: the cached assignment is global and
     # stable across calls, so each shard aggregates onto it directly
-    cached = (bound is not None and provided_slots(tuple(call.group_keys),
-                                                   bound) is not None)
+    if cached is None:
+        cached = (bound is not None and provided_slots(
+            tuple(call.group_keys), bound) is not None)
     cap = t.capacity
     mode = _resolve_grouped_mode(call, agg)
 
@@ -382,13 +378,19 @@ def grouped_agg_call(call: AggCall, catalog, env,
     for name, e in call.param_binding:
         if isinstance(e, Col):
             rows[name] = t.columns[e.name]
-        else:
+        elif env is not None:
             outer_vals[name] = eval_expr(e, env)
-    _default_missing_fields(agg, env, outer_vals, var_dtypes)
+    _default_missing_fields(agg, env or {}, outer_vals, var_dtypes)
 
-    from repro.launch.sharded_agg import launch_rows
-    sortfree = _sortfree_eligible(call, agg, mode, bound,
-                                  launch_rows(cap, shard_route))
+    launch = launch_rows(cap, shard_route)
+    sortfree = _sortfree_eligible(call, agg, mode, bound, launch,
+                                  nsegments)
+    kernel_updates = None
+    if env is not None and mode == "fused":
+        col_env = dict(outer_vals)
+        col_env.update(rows)
+        kernel_updates, rest = _split_kernel_updates(agg, outer_vals,
+                                                     col_env)
     updates_split = None
     if sortfree and shard_route is not None and not cached:
         # sharded sort-free assigns slots per shard inside the launcher —
@@ -397,16 +399,43 @@ def grouped_agg_call(call: AggCall, catalog, env,
         # updates included: past the f32-exact index ceiling their
         # legacy select tail needs global ids too
         from repro.kernels.segment_agg import index_moment_ok
-        col_env = dict(outer_vals)
-        col_env.update(rows)
-        kernel_updates, rest = _split_kernel_updates(agg, outer_vals,
-                                                     col_env)
-        if (mode != "fused" or rest or not kernel_updates
+        if (kernel_updates is None or rest or not kernel_updates
                 or (any(u.kind == "arg_group" for u in kernel_updates)
                     and not index_moment_ok(cap))):
             sortfree = False
         else:
             updates_split = (kernel_updates, rest)
+    from repro.kernels.segment_agg import resolve_backend
+    kernel = None if env is None else (
+        bool(kernel_updates)
+        and resolve_backend(_segagg_backend()) != "jnp")
+    return _CallSetup(mode, nsegments, bound, shard_route, cached, rows,
+                      outer_vals, updates_split,
+                      GroupRoute(sortfree, kernel, launch, nsegments))
+
+
+def grouped_call_route(call: AggCall, t: Table, env=None, *,
+                       cached: Optional[bool] = None) -> "GroupRoute":
+    """The route a grouped AggCall takes over its input table ``t``
+    (``relational.engine.grouped_route`` is the entry point)."""
+    return _grouped_call_setup(call, t, env, cached=cached).route
+
+
+def grouped_agg_call(call: AggCall, catalog, env,
+                     var_dtypes=None) -> Table:
+    agg: CustomAggregate = call.aggregate
+    t = _engine.execute_for_agg(call.child, catalog, env,
+                                _agg_call_needed(call))
+    from repro.relational.engine import segment_ids_for
+    from repro.relational.group_bound import (check_group_overflow,
+                                              poison_overflow)
+    from repro.relational.keyslot import (overflow_extended,
+                                          slot_segment_ids,
+                                          sortfree_result)
+    (mode, nsegments, bound, shard_route, cached, rows, outer_vals,
+     updates_split, route) = _grouped_call_setup(call, t, env, var_dtypes)
+    sortfree = route.sortfree
+    cap = t.capacity
 
     cols: dict[str, jax.Array] = {}
     if sortfree:
@@ -562,10 +591,11 @@ def _split_kernel_updates(agg, outer_vals, col_env):
             # fields): distinct keys that collide in f32 would mis-pick
             # the attaining row, so those route to the exact path too
             # (eval_shape: the dtype probe must not evaluate the N-row
-            # expression a second time under eager execution)
+            # expression a second time under eager execution; the
+            # environment is an argument, so abstract columns work too)
             ok = _f32_exact_key_dtype(
-                jax.eval_shape(lambda u=u: jnp.asarray(
-                    eval_expr(u.exprs[0], col_env))).dtype)
+                jax.eval_shape(lambda env, u=u: jnp.asarray(
+                    eval_expr(u.exprs[0], env)), col_env).dtype)
         (kernel_updates if ok else rest).append(u)
     return kernel_updates, rest
 

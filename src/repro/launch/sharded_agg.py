@@ -22,16 +22,17 @@ local rows and the winner's candidates combine with a masked ``psum``
 elements per shard — the payload gather never touches the global row
 set.
 
-Routing is transparent: ``row_sharded_mesh`` detects concrete arrays that
-carry a ``NamedSharding`` split over more than one device along dim 0, and
-the grouped executors (``core/executors.py`` grouped ``AggCall`` dispatch,
-``relational/engine.py`` ``GroupAgg``) send such tables through the
-sharded entry with no caller changes — ``Table.shard_rows(mesh, axis)`` is
-all a caller does.  Under tracing, arrays carry no committed sharding, so
-a caller that jits over row-sharded tables declares the split with
-``traced_row_sharding`` (the serving layer does): a Mosaic kernel cannot
-be partitioned by GSPMD and must run under ``shard_map``.
-``REPRO_SEGAGG_SHARDED=off`` disables routing.
+Routing is transparent: ``table_row_split`` reads the split a table
+declares (``Table.shard_rows(mesh, axis)`` sets ``Table.row_split``, a
+static pytree field, so it survives tracing — a Mosaic kernel cannot be
+partitioned by GSPMD and must run under ``shard_map`` inside ``jit`` as
+well) or, for an undeclared concrete table, detects arrays that carry a
+``NamedSharding`` split over more than one device along dim 0
+(``row_sharded_mesh``).  The grouped executors (``core/executors.py``
+grouped ``AggCall`` dispatch, ``relational/engine.py`` ``GroupAgg``) send
+such tables through the sharded entry with no caller changes.  The split
+is per table: a replicated table in the same plan keeps the one-device
+launch.  ``REPRO_SEGAGG_SHARDED=off`` disables routing.
 
 Rows arrive sorted by segment (the grouped executors sort to derive
 segment ids), so every contiguous row shard is itself sorted — the band
@@ -63,15 +64,14 @@ shards just contribute moment identities).
 Whole-plan fusion (relational/fuse.py) interacts with this routing at
 one seam: a fused chain's right-side column gathers produce fresh
 arrays whose sharding is whatever XLA picked, which would make
-``row_sharded_mesh`` miss the route.  ``fuse._recommit_rows`` puts each
-gathered column back on the left table's committed row NamedSharding
-before the aggregate sees it, so sharded fused chains still take the
-O(num_segments)-per-shard merge paths above with no changes here.
+the gathered columns miss the split.  ``fuse._recommit_rows`` puts each
+gathered column back on the left table's row NamedSharding, and the
+chain's table keeps the left table's ``row_split``, so sharded fused
+chains still take the O(num_segments)-per-shard merge paths above with
+no changes here.
 """
 from __future__ import annotations
 
-import threading
-from contextlib import contextmanager
 from typing import Optional
 
 import jax
@@ -91,37 +91,13 @@ from repro.kernels.segment_agg import (ARGMAX_ROW, ARGMIN_ROW, MOMENTS,
                                        resolve_backend)
 
 
-_TL = threading.local()
-
-
-@contextmanager
-def traced_row_sharding(route: Optional[tuple[Mesh, str]]):
-    """Declare, for the traces run in this thread's dynamic extent, that
-    the traced row arrays reaching the grouped executors are split as
-    ``route`` = (mesh, axis).  A tracer carries no committed sharding, so
-    a caller that jits over row-sharded tables (``AggServer`` does)
-    states it here; the executors then launch the kernel per shard under
-    ``shard_map``.  A Mosaic kernel cannot be partitioned by GSPMD, so
-    without the scope such a trace fails to compile on TPU."""
-    prev = getattr(_TL, "route", None)
-    _TL.route = route
-    try:
-        yield
-    finally:
-        _TL.route = prev
-
-
 def row_sharded_mesh(*arrays) -> Optional[tuple[Mesh, str]]:
-    """(mesh, axis) when any array carries a NamedSharding split over >1
-    device along dim 0, or — for tracers — the route an enclosing
-    ``traced_row_sharding`` scope declares; None for replicated arrays,
+    """(mesh, axis) when any concrete array carries a NamedSharding split
+    over >1 device along dim 0; None for replicated arrays, tracers,
     composite row axes, or when ``REPRO_SEGAGG_SHARDED=off``."""
     if not flags.enabled("REPRO_SEGAGG_SHARDED"):
         return None
-    traced = getattr(_TL, "route", None)
     for a in arrays:
-        if isinstance(a, jax.core.Tracer) and traced is not None:
-            return traced
         if a is None or isinstance(a, jax.core.Tracer):
             continue
         sh = getattr(a, "sharding", None)
@@ -138,6 +114,30 @@ def row_sharded_mesh(*arrays) -> Optional[tuple[Mesh, str]]:
         if sh.mesh.shape[ax] > 1:
             return sh.mesh, ax
     return None
+
+
+def table_row_split(t) -> Optional[tuple[Mesh, str]]:
+    """(mesh, axis) a Table's rows are split over: its declared
+    ``row_split`` (static, so it holds under tracing), else the committed
+    sharding of its concrete arrays; None when replicated, split over
+    one device, or when ``REPRO_SEGAGG_SHARDED=off``."""
+    if not flags.enabled("REPRO_SEGAGG_SHARDED"):
+        return None
+    if t.row_split is not None:
+        mesh, axis = t.row_split
+        return t.row_split if mesh.shape[axis] > 1 else None
+    return row_sharded_mesh(*t.columns.values(), t.valid)
+
+
+def declare_row_split(t):
+    """``t`` with its detected split declared (``Table.row_split``), so
+    a ``jit`` over it still launches per shard; ``t`` itself when there
+    is nothing to declare."""
+    route = table_row_split(t)
+    if route is None or route == t.row_split:
+        return t
+    import dataclasses
+    return dataclasses.replace(t, row_split=route)
 
 
 def launch_rows(n: int, route: Optional[tuple[Mesh, str]]) -> int:

@@ -9,7 +9,7 @@ between the query and the loop — the temp-table barrier.
 from __future__ import annotations
 
 import os
-from typing import Any, Mapping, Optional
+from typing import Any, Mapping, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -71,7 +71,7 @@ def _exec(plan: Plan, catalog: Catalog, env: Env) -> Table:
         from repro.core.loop_ir import Col as _Col
         keep = t.group_bound if all(isinstance(e, _Col)
                                     for _, e in plan.exprs) else None
-        return Table(cols, t.valid, keep)
+        return Table(cols, t.valid, keep, row_split=t.row_split)
 
     if isinstance(plan, Join):
         lt = _exec(plan.left, catalog, env)
@@ -304,8 +304,9 @@ def _apply_join(lt: Table, rt: Table, rkey: str, how: str,
     # the join introduces right-side columns the left table's declared
     # bound never covered — grouping the result by one of them could have
     # arbitrarily many groups, so the declaration must not survive
-    # (semi/anti joins returned earlier: they keep the left columns only)
-    return Table(cols, valid)
+    # (semi/anti joins returned earlier: they keep the left columns only);
+    # the rows are the left table's, and so is their split
+    return Table(cols, valid, row_split=lt.row_split)
 
 
 def _gather_join(lt: Table, rt: Table, lkey: str, rkey: str, how: str) -> Table:
@@ -402,47 +403,103 @@ def _groupagg_fused_backend() -> Optional[str]:
     return "pallas" if jax.default_backend() == "tpu" else None
 
 
-def sortfree_grid_fits(backend: Optional[str], rows: int,
-                       num_segments: int) -> bool:
-    """The sort-free route's kernel-grid gate, shared by ``GroupAgg``,
-    grouped ``AggCall`` and the serving layer's slot provisioning: with a
-    kernel backend, the unsorted cross-product grid over ``rows`` rows per
-    launch must stay within ``SORTFREE_GRID_RATIO`` × the sorted pruned
-    grid; jnp segment ops have no grid, so they always pass.  Reads
-    static shapes only."""
+class GroupRoute(NamedTuple):
+    """How a grouped node (``GroupAgg`` or grouped ``AggCall``) runs,
+    decided from static shapes, dtypes and the declared row split only.
+    ``sortfree``: hash-slotted segment ids in the kernel's unsorted
+    layout instead of the group sort.  ``kernel``: some aggregate runs on
+    the fused segment-aggregate kernel (None when a call's parameters
+    were not given).  ``rows``: rows one kernel launch walks (a shard's
+    slice when the input is row-split).  ``num_segments``: the segment
+    range."""
+    sortfree: bool
+    kernel: Optional[bool]
+    rows: int
+    num_segments: int
+
+    @property
+    def name(self) -> str:
+        return "sort-free" if self.sortfree else "sorted"
+
+    @property
+    def grid_steps(self) -> int:
+        """Kernel grid steps one launch runs; 0 off the kernel."""
+        from repro.kernels.segment_agg import (full_grid_steps,
+                                               launched_grid_steps)
+        if not self.kernel:
+            return 0
+        steps = full_grid_steps if self.sortfree else launched_grid_steps
+        return steps(self.rows, self.num_segments)
+
+
+def grouped_route(plan: Plan, t: Table, env: Optional[Env] = None, *,
+                  cached: Optional[bool] = None) -> GroupRoute:
+    """The route ``execute`` takes for the grouped node ``plan`` over its
+    input table ``t`` (concrete or abstract — ``jax.eval_shape`` of the
+    child plan will do).  ``env`` holds a grouped ``AggCall``'s
+    parameters (only their dtypes are read); ``cached`` says whether a
+    provided slot table (``keyslot.provide_slots``) covers the node, None
+    reading the active scope.  The executors decide through the same
+    functions, so a caller that provisions for the route (the serving
+    layer's slot cache) or reports it cannot disagree with the run."""
+    if isinstance(plan, GroupAgg):
+        return _group_agg_setup(t, plan.keys, plan.aggs, plan.max_groups,
+                                cached).route
+    from repro.core.executors import grouped_call_route
+    return grouped_call_route(plan, t, env, cached=cached)
+
+
+def kernel_grid_allows_sortfree(backend: Optional[str], rows: int,
+                                num_segments: int) -> bool:
+    """The sort-free route's kernel-grid gate: with a kernel backend, the
+    unsorted cross-product grid over ``rows`` rows per launch must stay
+    within ``SORTFREE_GRID_RATIO`` × the sorted pruned grid
+    (``segment_agg.sortfree_grid_ok``); jnp segment ops have no grid, so
+    they always pass."""
     from repro.kernels.segment_agg import resolve_backend, sortfree_grid_ok
     if backend in (None, "off") or resolve_backend(backend) == "jnp":
         return True
     return sortfree_grid_ok(rows, num_segments)
 
 
-def _group_agg(t: Table, keys: tuple[str, ...],
-               aggs: tuple[tuple[str, str, Optional[str]], ...],
-               max_groups: Optional[int] = None) -> Table:
-    from .group_bound import (check_group_overflow, poison_overflow,
-                              resolve_group_bound)
-    from .keyslot import (overflow_extended, provided_slots,
-                          slot_segment_ids, sortfree_enabled,
-                          sortfree_result)
+class _GroupAggSetup(NamedTuple):
+    backend: Optional[str]
+    nsegments: int
+    bound: Optional[int]
+    shard_route: Optional[tuple]
+    cached: bool
+    fused_aggs: list
+    rest_aggs: tuple
+    route: GroupRoute
+
+
+def _group_agg_setup(t: Table, keys: tuple[str, ...], aggs,
+                     max_groups: Optional[int],
+                     cached: Optional[bool] = None) -> _GroupAggSetup:
+    """Every static decision of ``_group_agg``: backend, segment range,
+    row split, which ops ride the fused kernel pass, and the route."""
+    from repro.launch.sharded_agg import launch_rows, table_row_split
+    from .group_bound import resolve_group_bound
+    from .keyslot import provided_slots, sortfree_enabled
     backend = _groupagg_fused_backend()
     # dense segment range: plan-declared max_groups beats the table hint;
     # without either, the row capacity is the only static bound available
     declared = max_groups if max_groups is not None else t.group_bound
     nsegments, bound = resolve_group_bound(declared, t.capacity)
     cap = t.capacity
-    # a row-sharded input table (Table.shard_rows) routes the fused pass
+    # a row-split input table (Table.shard_rows) routes the fused pass
     # through the mesh — one kernel launch per row shard, moments
-    # all-reduced; detect on the caller-committed columns, pre-sort.  A
-    # provide_slots scope carrying this call's slot table replaces the
-    # launcher's per-shard slotting: the cached assignment is GLOBAL
-    # (stable across calls), so each shard aggregates onto it directly.
+    # all-reduced.  A provide_slots scope carrying this call's slot table
+    # replaces the launcher's per-shard slotting: the cached assignment
+    # is GLOBAL (stable across calls), so each shard aggregates onto it
+    # directly.
     shard_route = None
     if backend != "off":
-        from repro.launch.sharded_agg import row_sharded_mesh
-        shard_route = row_sharded_mesh(*t.columns.values(), t.valid)
+        shard_route = table_row_split(t)
         if backend is None and shard_route is not None:
             backend = "auto"    # distributed beats per-op even off-TPU
-    cached = bound is not None and provided_slots(keys, bound) is not None
+    if cached is None:
+        cached = bound is not None and provided_slots(keys, bound) is not None
 
     def _fusable(op, col):
         # kernel accumulates in f32: float64 columns keep the exact per-op
@@ -474,16 +531,32 @@ def _group_agg(t: Table, keys: tuple[str, ...],
     # (commutative merge algebra), so whenever a dense bound is declared
     # the hash-slotted segment assignment (relational/keyslot.py) replaces
     # the group sort — unless the kernel's unsorted grid would dwarf the
-    # sorted one (sortfree_grid_fits).  Sharded inputs without cached
-    # slots additionally need every op on the fused pass — slots are
-    # assigned per shard inside the launcher, so the per-op segment
-    # fallbacks have no global ids.
-    from repro.launch.sharded_agg import launch_rows
+    # sorted one.  Sharded inputs without cached slots additionally need
+    # every op on the fused pass — slots are assigned per shard inside
+    # the launcher, so the per-op segment fallbacks have no global ids.
+    rows = launch_rows(cap, shard_route)
     sortfree = (bound is not None and sortfree_enabled()
                 and not (shard_route is not None and not cached
                          and (rest_aggs or not fused_aggs))
-                and (not fused_aggs or sortfree_grid_fits(
-                    backend, launch_rows(cap, shard_route), nsegments)))
+                and (not fused_aggs or kernel_grid_allows_sortfree(
+                    backend, rows, nsegments)))
+    from repro.kernels.segment_agg import resolve_backend
+    kernel = bool(fused_aggs) and resolve_backend(backend) != "jnp"
+    return _GroupAggSetup(backend, nsegments, bound, shard_route, cached,
+                          fused_aggs, rest_aggs,
+                          GroupRoute(sortfree, kernel, rows, nsegments))
+
+
+def _group_agg(t: Table, keys: tuple[str, ...],
+               aggs: tuple[tuple[str, str, Optional[str]], ...],
+               max_groups: Optional[int] = None) -> Table:
+    from .group_bound import check_group_overflow, poison_overflow
+    from .keyslot import (overflow_extended, slot_segment_ids,
+                          sortfree_result)
+    (backend, nsegments, bound, shard_route, cached, fused_aggs, rest_aggs,
+     route) = _group_agg_setup(t, keys, aggs, max_groups)
+    sortfree = route.sortfree
+    cap = t.capacity
 
     cols: dict[str, jax.Array] = {}
     if sortfree and shard_route is not None and not cached:

@@ -178,12 +178,12 @@ class _ChainEnv(Mapping):
 
 
 def _recommit_rows(arrays: list, template: Table) -> list:
-    """Gathered right-side columns lose the left table's committed row
-    sharding (the gather output lands wherever XLA puts it) — put them
-    back on the left rows' NamedSharding so ``row_sharded_mesh`` still
-    detects the distributed aggregate route downstream."""
-    from repro.launch.sharded_agg import row_sharded_mesh
-    route = row_sharded_mesh(*template.columns.values(), template.valid)
+    """Gathered right-side columns lose the left table's row sharding
+    (the gather output lands wherever XLA puts it) — put them back on the
+    left rows' NamedSharding, matching the ``row_split`` the chain's
+    table keeps."""
+    from repro.launch.sharded_agg import table_row_split
+    route = table_row_split(template)
     if route is None:
         return arrays
     mesh, axis = route
@@ -249,8 +249,8 @@ def execute_chain(chain: FusedChain, catalog, env: Mapping[str, Any],
     # Filter/semi-join preservation rule); any gathered right column
     # voids it, as in the materialized join
     bound = lt.group_bound if from_left else None
-    return ChainResult(Table(cols, valid, bound), chain, ridx, found,
-                       rt.capacity)
+    return ChainResult(Table(cols, valid, bound, row_split=lt.row_split),
+                       chain, ridx, found, rt.capacity)
 
 
 def fused_chain_result(child: Plan, catalog, env: Mapping[str, Any],

@@ -41,18 +41,25 @@ class Table:
     #: served stale data.  Not part of traced state.
     version: int = field(default_factory=lambda: next(_VERSIONS),
                          compare=False)
+    #: (mesh, axis) the rows are split over (``shard_rows``): static
+    #: metadata that rides in the pytree treedef, so a traced table still
+    #: states its split and the grouped executors launch the kernel per
+    #: row shard under ``shard_map`` (GSPMD cannot partition a Mosaic
+    #: kernel).  Ops that keep the table's row axis propagate it; concat
+    #: and head drop it.
+    row_split: Optional[tuple] = None
 
     # -- pytree ---------------------------------------------------------------
     def tree_flatten(self):
         names = tuple(sorted(self.columns))
         children = tuple(self.columns[n] for n in names) + (self.valid,)
-        return children, (names, self.group_bound)
+        return children, (names, self.group_bound, self.row_split)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        names, group_bound = aux
+        names, group_bound, row_split = aux
         cols = dict(zip(names, children[:-1]))
-        return cls(cols, children[-1], group_bound)
+        return cls(cols, children[-1], group_bound, row_split=row_split)
 
     # -- construction ---------------------------------------------------------
     @staticmethod
@@ -80,29 +87,30 @@ class Table:
     # -- row ops ---------------------------------------------------------------
     def filter(self, mask: jax.Array) -> "Table":
         return Table(dict(self.columns), self.mask() & mask,
-                     self.group_bound)
+                     self.group_bound, row_split=self.row_split)
 
     def project(self, names: Iterable[str]) -> "Table":
         return Table({n: self.columns[n] for n in names}, self.valid,
-                     self.group_bound)
+                     self.group_bound, row_split=self.row_split)
 
     def with_column(self, name: str, values: jax.Array) -> "Table":
         cols = dict(self.columns)
         cols[name] = values
         # a new column may have more distinct values than the declared
         # group bound covers, so the declaration does not survive
-        return Table(cols, self.valid)
+        return Table(cols, self.valid, row_split=self.row_split)
 
     def rename(self, mapping: Mapping[str, str]) -> "Table":
         cols = {mapping.get(k, k): v for k, v in self.columns.items()}
-        return Table(cols, self.valid, self.group_bound)
+        return Table(cols, self.valid, self.group_bound,
+                     row_split=self.row_split)
 
     def take(self, idx: jax.Array, idx_valid: Optional[jax.Array] = None) -> "Table":
         cols = {k: jnp.take(v, idx, axis=0, mode="clip")
                 for k, v in self.columns.items()}
         base = jnp.take(self.mask(), idx, mode="clip")
         v = base if idx_valid is None else base & idx_valid
-        return Table(cols, v, self.group_bound)
+        return Table(cols, v, self.group_bound, row_split=self.row_split)
 
     def compress(self) -> "Table":
         """Stable-compact valid rows to the front (fixed capacity)."""
@@ -111,7 +119,7 @@ class Table:
         t = self.take(order)
         n = jnp.sum(m.astype(jnp.int32))
         return Table(t.columns, jnp.arange(self.capacity) < n,
-                     self.group_bound)
+                     self.group_bound, row_split=self.row_split)
 
     def sort_by(self, keys: Iterable[str], descending: Iterable[bool] = ()) -> "Table":
         """Stable multi-key sort; invalid rows sort last.
@@ -153,26 +161,27 @@ class Table:
         treedef and jitted callers don't retrace per distinct value."""
         from .group_bound import bucket_group_bound
         return Table(dict(self.columns), self.valid,
-                     bucket_group_bound(max_groups))
+                     bucket_group_bound(max_groups), row_split=self.row_split)
 
     def shard_rows(self, mesh, axis: str = "data") -> "Table":
         """Commit every column (and the validity mask) to a row sharding —
-        ``PartitionSpec(axis)`` on dim 0 — over ``mesh``.  The grouped
-        fused-aggregation path (``GroupAgg`` and grouped ``AggCall``)
-        detects the committed sharding and runs the segment-aggregate
-        kernel per row shard with a cross-device moment merge
-        (``launch/sharded_agg.py``) — no other caller changes needed."""
+        ``PartitionSpec(axis)`` on dim 0 — over ``mesh``, and declare the
+        split (``row_split``).  The grouped fused-aggregation path
+        (``GroupAgg`` and grouped ``AggCall``) reads the declaration and
+        runs the segment-aggregate kernel per row shard with a
+        cross-device moment merge (``launch/sharded_agg.py``), eagerly
+        and under ``jit`` alike — no other caller changes needed."""
         from jax.sharding import NamedSharding, PartitionSpec
         sh = NamedSharding(mesh, PartitionSpec(axis))
         cols = {k: jax.device_put(v, sh) for k, v in self.columns.items()}
         return Table(cols, jax.device_put(self.mask(), sh),
-                     self.group_bound)
+                     self.group_bound, row_split=(mesh, axis))
 
     def materialize(self) -> "Table":
         """Force device materialization — models the cursor temp table."""
         cols = {k: jax.block_until_ready(jnp.asarray(v)) for k, v in self.columns.items()}
         v = None if self.valid is None else jax.block_until_ready(self.valid)
-        return Table(cols, v, self.group_bound)
+        return Table(cols, v, self.group_bound, row_split=self.row_split)
 
     def nbytes(self) -> int:
         tot = 0

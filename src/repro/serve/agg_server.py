@@ -79,8 +79,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import flags
+from repro.launch.sharded_agg import declare_row_split
 from repro.relational import keyslot
-from repro.relational.engine import execute
+from repro.relational.engine import execute, grouped_route
 from repro.relational.group_bound import GroupBoundOverflow, resolve_group_bound
 from repro.relational.keyslot import check_slot_overflow
 from repro.relational.plan import AggCall, GroupAgg, Plan, Scan
@@ -236,7 +237,10 @@ class AggServer:
                  infer_bounds: bool = True, guard: Optional[bool] = None,
                  max_queue: int = 1024, breaker_threshold: int = 3,
                  breaker_cooldown_s: float = 5.0, breaker_clock=None):
-        self._catalog: Dict[str, Table] = dict(catalog)
+        # a row-sharded table's split is declared on the table itself, so
+        # the executables traced over it launch the kernel per shard
+        self._catalog: Dict[str, Table] = {
+            name: declare_row_split(t) for name, t in catalog.items()}
         self._max_batch = max(1, int(max_batch))
         self._batch_window = float(batch_window_s)
         self._infer_bounds = bool(infer_bounds)
@@ -346,7 +350,7 @@ class AggServer:
                     "full-replace semantics: executables, slot tables, and "
                     "resident state for this table are all invalidated.",
                     DeprecationWarning, stacklevel=2)
-            self._catalog[name] = table
+            self._catalog[name] = declare_row_split(table)
             self._invalidate(name)
 
     def append_rows(self, name: str, rows) -> int:
@@ -382,7 +386,8 @@ class AggServer:
                 jnp.asarray(cols[c]).astype(a.dtype))
                 for c, a in t.columns.items()}
             new_valid = jnp.asarray(mask).at[posj].set(True)
-            t2 = Table(new_cols, new_valid, t.group_bound)
+            t2 = Table(new_cols, new_valid, t.group_bound,
+                       row_split=t.row_split)
             self._catalog[name] = t2
             self._appends[(name, t2.version)] = (prev_version, pos)
             self._trim_appends(name)
@@ -518,7 +523,7 @@ class AggServer:
             [a, jnp.zeros((extra,) + a.shape[1:], a.dtype)])
             for c, a in t.columns.items()}
         valid = jnp.concatenate([t.mask(), jnp.zeros(extra, bool)])
-        return Table(cols, valid, t.group_bound)
+        return Table(cols, valid, t.group_bound, row_split=t.row_split)
 
     _MAX_APPEND_CHAIN = 64
 
@@ -1077,23 +1082,6 @@ class AggServer:
             return plan, tuple(plan.group_keys)
         return None, ()
 
-    @staticmethod
-    def _takes_sortfree(plan: Plan, bound: Optional[int], t: Table) -> bool:
-        if bound is None or not keyslot.sortfree_enabled():
-            return False
-        from repro.launch.sharded_agg import launch_rows, row_sharded_mesh
-        rows = launch_rows(t.capacity,
-                           row_sharded_mesh(*t.columns.values(), t.valid))
-        if isinstance(plan, GroupAgg):
-            # every GroupAgg op is an order-insensitive moment; only the
-            # kernel grid can send it down the sorted route
-            from repro.relational.engine import (_groupagg_fused_backend,
-                                                 sortfree_grid_fits)
-            return sortfree_grid_fits(_groupagg_fused_backend(), rows,
-                                      bound + 1)
-        from repro.core.executors import sortfree_call_route
-        return sortfree_call_route(plan, bound, rows)
-
     def _prepare(self, plan: Plan) -> _PlanEntry:
         ent = self._plans.get(id(plan))
         if ent is not None:
@@ -1122,8 +1110,10 @@ class AggServer:
                         declared = mg
                 if declared is not None:
                     _, bound = resolve_group_bound(declared, t.capacity)
-                    if bound is not None and \
-                            self._takes_sortfree(ent.plan, bound, t):
+                    # provision slots exactly when the engine, handed
+                    # them, takes the sort-free route
+                    if bound is not None and grouped_route(
+                            ent.plan, t, cached=True).sortfree:
                         ent.keys = keys
                         ent.bound = bound
                         ent.slot_scan = scan
@@ -1246,7 +1236,7 @@ class AggServer:
     # -- executables -------------------------------------------------------
     def _catalog_sig(self):
         return tuple(
-            (name, t.group_bound, t.valid is None,
+            (name, t.group_bound, t.row_split, t.valid is None,
              tuple((c, str(a.dtype), tuple(a.shape))
                    for c, a in sorted(t.columns.items())))
             for name, t in sorted(self._catalog.items()))
@@ -1266,18 +1256,9 @@ class AggServer:
         return fn
 
     def _build(self, ent: _PlanEntry, psig, nb: int, degraded: bool = False):
-        from repro.launch.sharded_agg import (row_sharded_mesh,
-                                              traced_row_sharding)
         plan = ent.plan
         spec = (ent.keys, ent.bound) if ent.slot_scan is not None else None
         stats = self.stats
-        # the tables arrive as tracers, which carry no sharding: declare
-        # the row split of the scanned tables so the kernel runs per shard
-        route = row_sharded_mesh(*(
-            a for name in self._plan_tables(ent.submitted)
-            if name in self._catalog
-            for a in (*self._catalog[name].columns.values(),
-                      self._catalog[name].valid)))
 
         def run(tables, slots, pvec):
             stats.traces += 1    # Python side effect: counts traces only
@@ -1293,7 +1274,7 @@ class AggServer:
                 with keyslot.provide_slots({spec: slots}):
                     return execute(plan, tables, env)
 
-            with ctx, traced_row_sharding(route):
+            with ctx:
                 if not psig:
                     return one({})
                 return jax.vmap(one)(pvec)

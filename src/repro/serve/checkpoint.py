@@ -310,7 +310,8 @@ def rehydrate(server, ent):
         jnp.asarray(got["cnt"]), int(rec["bucket"]), int(rec["expand"]))
     payloads = {n: jnp.asarray(got["pays"][j])
                 for j, n in enumerate(rec["payload_names"])}
-    wtable = Table(live.columns, jnp.asarray(padded), live.group_bound)
+    wtable = Table(live.columns, jnp.asarray(padded), live.group_bound,
+                   row_split=live.row_split)
     server._synth_version -= 1
     synth = server._synth_version
     ep = incremental.Epoch(

@@ -429,8 +429,8 @@ class ResidentAgg:
         vals_b = self._vals(bcols, nb)
         arg_names = [name for name, *_rest in self._arg_aggs()]
 
-        from repro.launch.sharded_agg import row_sharded_mesh
-        route = row_sharded_mesh(*table.columns.values(), table.valid)
+        from repro.launch.sharded_agg import table_row_split
+        route = table_row_split(table)
         if route is not None:
             from repro.launch.sharded_agg import sharded_fold_batch
             specs = tuple((i, minimize, (bcols[pc],))

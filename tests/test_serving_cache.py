@@ -83,6 +83,40 @@ def test_compiled_text_reads_the_served_executable():
     assert srv.describe(plan)["executables"] == 1
 
 
+@pytest.mark.parametrize("col,sortfree", [("iv", True), ("v", False)])
+def test_slot_provisioning_follows_the_engine_route(monkeypatch, col,
+                                                    sortfree):
+    """High-cardinality grouping under a kernel backend: an op the kernel
+    cannot take (an int column) keeps the engine on the sort-free route,
+    so the server caches its slot table; a kernel op sends the engine
+    down the sorted route (the kernel-grid rule), so the server builds
+    none.  Either way the served result equals the sorted one."""
+    from repro.relational.engine import grouped_route
+    monkeypatch.setenv("REPRO_GROUPAGG_FUSED", "interpret")
+    n, card = 20000, 10000
+    rng = np.random.default_rng(5)
+    t = Table({"k": jnp.asarray(rng.integers(0, card, n).astype(np.int32)),
+               "v": jnp.asarray(rng.integers(-4, 5, n).astype(np.float32)),
+               "iv": jnp.asarray(rng.integers(-4, 5, n).astype(np.int32))},
+              jnp.ones(n, bool))
+    plan = GroupAgg(Scan("T", ("k", "v", "iv")), ("k",),
+                    (("s", "sum", col),), max_groups=card)
+    assert grouped_route(plan, t, cached=True).sortfree == sortfree
+    srv = AggServer({"T": t})
+    before = keyslot.slot_build_count()
+    got = srv.execute(plan)
+    srv.execute(plan)
+    assert (srv.describe(plan)["slot_scan"] == "T") == sortfree
+    assert srv.stats.slot_builds == keyslot.slot_build_count() - before \
+        == int(sortfree)
+    monkeypatch.setenv("REPRO_GROUPAGG_SORTFREE", "off")
+    want = execute(plan, {"T": t}).to_numpy()
+    got = got.to_numpy()
+    og, ow = np.argsort(got["k"]), np.argsort(want["k"])
+    for c in want:
+        np.testing.assert_array_equal(got[c][og], want[c][ow], err_msg=c)
+
+
 def test_mutation_rebuilds_slots_once_from_new_version(monkeypatch):
     t = _table()
     srv = AggServer({"T": t})

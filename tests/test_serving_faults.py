@@ -336,10 +336,13 @@ def test_unexpired_deadline_serves():
 def test_queue_full_rejects_typed():
     srv, t, plan, keys, aggs, envs = _fresh(CASE_FILTERED, max_queue=2)
     with srv:
-        # hold the launch lock so dequeued work blocks and the queue fills
+        # hold the launch lock so dequeued work blocks and the queue fills:
+        # the dispatcher takes at most one batch (≤ max_queue requests)
+        # before it blocks, so of 2 × max_queue + 2 submits at least two
+        # must be turned away, however the threads interleave
         with srv._lock:
             futs = [srv.submit(plan, envs[i % len(envs)])
-                    for i in range(4)]
+                    for i in range(6)]
         rejected = [f for f in futs
                     if isinstance(f.exception(timeout=120), QueueFull)]
         served = [f for f in futs if f not in rejected]

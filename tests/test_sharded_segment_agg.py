@@ -318,3 +318,79 @@ print("OK")
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, env=env)
     assert r.returncode == 0 and "OK" in r.stdout, r.stderr
+
+
+def test_row_split_is_per_table_in_subprocess_4way_mesh():
+    """One plan over a row-split fact table and a replicated dimension
+    table, served (traced under ``jit``) and run directly: the grouped
+    node whose rows are the fact table's launches per shard; the one
+    whose rows are the dimension table's stays on one device, though the
+    plan also scans the split table.  Both equal the one-device run."""
+    code = """
+import numpy as np, jax
+assert jax.device_count() == 4, jax.device_count()
+from jax.sharding import Mesh
+from repro.relational import Table, execute
+from repro.relational.engine import grouped_route
+from repro.relational.plan import GroupAgg, Join, Scan
+from repro.serve import AggServer
+import repro.launch.sharded_agg as sa
+
+mesh = Mesh(np.asarray(jax.devices()).reshape(4), ("data",))
+rng = np.random.default_rng(3)
+n, nd = 4096, 50
+F = Table.from_columns(fk=rng.integers(0, nd, n).astype(np.int32),
+                       id=np.arange(n, dtype=np.int32),
+                       v=rng.integers(-9, 9, n).astype(np.float32))
+D = Table.from_columns(dk=np.arange(nd, dtype=np.int32),
+                       g=(np.arange(nd) % 8).astype(np.int32),
+                       w=rng.integers(0, 9, nd).astype(np.float32))
+fact_rows = GroupAgg(Join(Scan("F", ("fk", "id", "v")),
+                          Scan("D", ("dk", "g", "w")), "fk", "dk"),
+                     ("g",), (("s", "sum", "v"), ("mx", "max", "w")),
+                     max_groups=8)
+dim_rows = GroupAgg(Join(Scan("D", ("dk", "g", "w")),
+                         Scan("F", ("fk", "id", "v")), "dk", "id"),
+                    ("g",), (("s", "sum", "v"), ("mx", "max", "w")),
+                    max_groups=8)
+one = {"F": F, "D": D}
+split = {"F": F.shard_rows(mesh, "data"), "D": D}
+assert split["F"].row_split == (mesh, "data") and split["D"].row_split is None
+
+calls = []
+for name in ("sharded_fused_segment_agg", "sharded_sortfree_segment_agg"):
+    orig = getattr(sa, name)
+    setattr(sa, name, lambda *a, _o=orig, **k: (calls.append(1),
+                                                _o(*a, **k))[1])
+
+def same(a, b):
+    a, b = a.to_numpy(), b.to_numpy()
+    oa, ob = np.argsort(a["g"]), np.argsort(b["g"])
+    return all(np.array_equal(a[c][oa], b[c][ob]) for c in a)
+
+srv = AggServer(split)
+for plan, per_shard in ((fact_rows, True), (dim_rows, False)):
+    want = execute(plan, one)
+    t = jax.eval_shape(lambda c: execute(plan.child, c), split)
+    r = grouped_route(plan, t)
+    assert (r.rows == n // 4) == per_shard, r
+    for run in (lambda: execute(plan, split),
+                lambda: jax.jit(lambda c: execute(plan, c))(split),
+                lambda: srv.execute(plan)):
+        calls.clear()
+        got = run()
+        assert bool(calls) == per_shard, (plan is fact_rows, calls)
+        assert same(want, got)
+srv.close()
+print("OK")
+"""
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = {**os.environ,
+           "JAX_PLATFORMS": "cpu",
+           "XLA_FLAGS": (os.environ.get("XLA_FLAGS", "") +
+                         " --xla_force_host_platform_device_count=4"),
+           "PYTHONPATH": os.path.abspath(src) + os.pathsep +
+                         os.environ.get("PYTHONPATH", "")}
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, env=env)
+    assert r.returncode == 0 and "OK" in r.stdout, r.stderr

@@ -24,6 +24,9 @@ from repro.kernels.segment_agg import (fused_segment_agg,
                                        launched_grid_steps,
                                        normalize_moments)
 from repro.launch.sharded_agg import sharded_fused_segment_agg
+from repro.relational import Table, execute, keyslot
+from repro.relational.group_bound import resolve_group_bound
+from repro.relational.plan import GroupAgg, Scan
 
 LINEITEM_SF10 = 60_000_000
 PARTSUPP_SF10 = 8_000_000
@@ -86,14 +89,53 @@ def test_index_moment_kernel_q2_partsupp(one_chip):
     assert "tpu_custom_call" in txt
 
 
-def test_sharded_fused_segment_agg_four_chips(topo):
-    mesh = Mesh(np.asarray(topo.devices[:4]), ("data",))
-    rows = NamedSharding(mesh, P("data"))
-    nseg = (1 << 17) + 1
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    return Mesh(np.asarray(topo.devices[:4]), ("data",))
+
+
+@pytest.mark.parametrize("layout,nseg", [("sorted", (1 << 17) + 1),
+                                         ("unsorted", 4097)])
+def test_sharded_fused_segment_agg_four_chips(mesh4, layout, nseg):
+    # sorted: Q21's pruned launch per shard; unsorted: the global slot
+    # ids the serving layer caches for a row-split table (tile_ship's
+    # ship-date domain), each shard's kernel under shard_map
+    rows = NamedSharding(mesh4, P("data"))
     txt = _compiled_text(
         lambda v, s, g: sharded_fused_segment_agg(
-            v, s, g, nseg, mesh=mesh, axis="data", backend="pallas",
-            moments=("sum", "count"), assume_sorted=True),
+            v, s, g, nseg, mesh=mesh4, axis="data", backend="pallas",
+            moments=("sum", "count", "max"), assume_sorted=True,
+            layout=layout),
         *_args(LINEITEM_SF10, 1, rows))
+    assert "tpu_custom_call" in txt
+    assert "all-reduce" in txt
+
+
+def test_served_groupagg_over_row_split_table_four_chips(mesh4,
+                                                         monkeypatch):
+    """What ``AggServer`` traces for a ``shard_rows`` table: the table's
+    declared split rides through ``jit`` and the cached global slots feed
+    each shard's unsorted kernel — GSPMD alone cannot partition it."""
+    monkeypatch.setenv("REPRO_GROUPAGG_FUSED", "pallas")
+    rows = NamedSharding(mesh4, P("data"))
+    n = LINEITEM_SF10
+    _, bound = resolve_group_bound(2556, n)
+    t = Table({"d": jax.ShapeDtypeStruct((n,), jnp.int32, sharding=rows),
+               "q": jax.ShapeDtypeStruct((n,), jnp.float32, sharding=rows)},
+              jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=rows),
+              row_split=(mesh4, "data"))
+    slots = (jax.ShapeDtypeStruct((n,), jnp.int32, sharding=rows),
+             jax.ShapeDtypeStruct((bound,), jnp.int32),
+             jax.ShapeDtypeStruct((bound,), jnp.bool_),
+             jax.ShapeDtypeStruct((), jnp.int32))
+    plan = GroupAgg(Scan("L", ("d", "q")), ("d",),
+                    (("qty", "sum", "q"), ("hi", "max", "q")),
+                    max_groups=2556)
+
+    def run(tables, slots):
+        with keyslot.provide_slots({(("d",), bound): slots}):
+            return execute(plan, tables).columns
+
+    txt = _compiled_text(run, {"L": t}, slots)
     assert "tpu_custom_call" in txt
     assert "all-reduce" in txt
