@@ -585,7 +585,7 @@ def _segment_agg_pallas(vals: jax.Array, segs: jax.Array, valid: jax.Array,
             ],
             out_specs=pl.BlockSpec((out_rows, block_segs),
                                    lambda j, i: (0, j)),
-            interpret=interpret,
+            interpret=interpret, name="segment_agg_unsorted",
         )(vals2, segs2, valid2)
         return out[:, :num_segments].reshape(num_cols, nrows, num_segments)
 
@@ -619,6 +619,7 @@ def _segment_agg_pallas(vals: jax.Array, segs: jax.Array, valid: jax.Array,
         out = pl.pallas_call(
             kernel, out_shape=out_shape, grid_spec=grid_spec,
             input_output_aliases={6: 0}, interpret=interpret,
+            name="segment_agg_sorted",
         )(rowm + b0, tilem, nsteps.reshape(1), vals2, segs2, valid2, out)
 
     if check_sorted:

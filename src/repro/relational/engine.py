@@ -268,10 +268,11 @@ def _join_lookup(lt: Table, rt: Table, lkey: str, rkey: str,
     *lookup*; materializing joined columns (``_apply_join``) is separate
     so the fusion pass can consume the lookup directly.
     """
-    lk, rk = _common_key_cast(lt.columns[lkey], rt.columns[rkey])
-    if join_hash_enabled():
-        return _hash_lookup(lk, rk, rt.mask())
-    return _sorted_lookup(lk, rk, rt.mask())
+    with jax.named_scope("join.probe"):
+        lk, rk = _common_key_cast(lt.columns[lkey], rt.columns[rkey])
+        if join_hash_enabled():
+            return _hash_lookup(lk, rk, rt.mask())
+        return _sorted_lookup(lk, rk, rt.mask())
 
 
 def _apply_join(lt: Table, rt: Table, rkey: str, how: str,

@@ -73,7 +73,7 @@ __all__ = [
     "provided_slots", "slot_build_count", "distinct_count_sketch",
     "adaptive_expand", "adaptive_enabled", "probe_rounds",
     "SlotState", "fresh_slot_state", "slot_ids_extend",
-    "slot_state_build", "slot_extend_count",
+    "slot_state_build",
 ]
 
 
@@ -377,6 +377,12 @@ def slot_ids_extend(words: jax.Array, valid: jax.Array,
     stepped over is occupied at call end: probe paths stay consistent
     across calls (absent overflow).
     """
+    return _slot_extend(words, valid, state, "keyslot.extend")
+
+
+def _slot_extend(words, valid, state: SlotState, scope: str):
+    """``slot_ids_extend`` with the device ops named ``scope`` in the
+    trace (a build and an extension run the same probe loop)."""
     bucket, expand = state.bucket, state.expand
     words = jnp.asarray(words)
     if state.ktab.shape[1] != words.shape[1]:
@@ -386,70 +392,72 @@ def slot_ids_extend(words: jax.Array, valid: jax.Array,
     seg, new_owner, overflowed, tbl, ktab, cnt = _extend_probe(
         words, jnp.asarray(valid, bool), jnp.asarray(state.tbl),
         jnp.asarray(state.ktab), jnp.asarray(state.cnt, jnp.int32),
-        bucket=bucket, expand=expand)
+        bucket=bucket, expand=expand, scope=scope)
     return seg, new_owner, overflowed, SlotState(tbl, ktab, cnt,
                                                  bucket, expand)
 
 
-@partial(jax.jit, static_argnames=("bucket", "expand"))
+@partial(jax.jit, static_argnames=("bucket", "expand", "scope"))
 def _extend_probe(words, valid, state_tbl, state_ktab, state_cnt, *,
-                  bucket: int, expand: int):
+                  bucket: int, expand: int, scope: str):
     # jitted per (batch shape, bucket, expand): the probe while_loop is
     # traced once per shape instead of on every eager call — sustained
-    # ingest folds hit this thousands of times
-    m = bucket * expand
-    n, k = words.shape
-    h = _hash_words(words)
-    idx = jnp.arange(n, dtype=jnp.int32)
-    mask = jnp.uint32(m - 1)
-    scratch_rows = bucket + n          # overflow claims park past bucket
-    ktab_s = jnp.concatenate(
-        [state_ktab, jnp.zeros((n, k), jnp.uint32)], axis=0)
+    # ingest folds hit this thousands of times.  The scope is named
+    # inside the jit: a scope around an eager call does not reach it.
+    with jax.named_scope(scope):
+        m = bucket * expand
+        n, k = words.shape
+        h = _hash_words(words)
+        idx = jnp.arange(n, dtype=jnp.int32)
+        mask = jnp.uint32(m - 1)
+        scratch_rows = bucket + n          # overflow claims park past bucket
+        ktab_s = jnp.concatenate(
+            [state_ktab, jnp.zeros((n, k), jnp.uint32)], axis=0)
 
-    def cond(st):
-        _t, _k, _o, _c, _s, active, rnd = st
-        return (rnd < m) & jnp.any(active)
+        def cond(st):
+            _t, _k, _o, _c, _s, active, rnd = st
+            return (rnd < m) & jnp.any(active)
 
-    def body(st):
-        tbl, ktab, own_arr, cnt, slot, active, rnd = st
-        p = rnd.astype(jnp.uint32)
-        cand = ((h + (p * (p + 1)) // 2) & mask).astype(jnp.int32)
-        empty = jnp.take(tbl, cand, mode="clip") < 0
-        claim = jnp.full((m,), n, jnp.int32).at[cand].min(
-            jnp.where(active & empty, idx, n), mode="promise_in_bounds")
-        winner = active & empty & (jnp.take(claim, cand,
-                                            mode="clip") == idx)
-        rank = jnp.cumsum(winner.astype(jnp.int32)) - 1
-        newid = cnt + rank
-        tbl = tbl.at[jnp.where(winner, cand, m)].set(newid, mode="drop")
-        ktab = ktab.at[jnp.where(winner, newid, scratch_rows)].set(
-            words, mode="drop")
-        own_arr = own_arr.at[jnp.where(winner, newid, bucket)].set(
-            idx, mode="drop")
-        cnt = cnt + jnp.sum(winner.astype(jnp.int32))
-        own = jnp.take(tbl, cand, mode="clip")
-        ow = jnp.take(ktab, jnp.clip(own, 0, scratch_rows - 1), axis=0,
-                      mode="clip")
-        eq = (own >= 0) & jnp.all(ow == words, axis=1)
-        slot = jnp.where(active & eq, own, slot)
-        active = active & ~eq
-        return tbl, ktab, own_arr, cnt, slot, active, rnd + 1
+        def body(st):
+            tbl, ktab, own_arr, cnt, slot, active, rnd = st
+            p = rnd.astype(jnp.uint32)
+            cand = ((h + (p * (p + 1)) // 2) & mask).astype(jnp.int32)
+            empty = jnp.take(tbl, cand, mode="clip") < 0
+            claim = jnp.full((m,), n, jnp.int32).at[cand].min(
+                jnp.where(active & empty, idx, n), mode="promise_in_bounds")
+            winner = active & empty & (jnp.take(claim, cand,
+                                                mode="clip") == idx)
+            rank = jnp.cumsum(winner.astype(jnp.int32)) - 1
+            newid = cnt + rank
+            tbl = tbl.at[jnp.where(winner, cand, m)].set(newid, mode="drop")
+            ktab = ktab.at[jnp.where(winner, newid, scratch_rows)].set(
+                words, mode="drop")
+            own_arr = own_arr.at[jnp.where(winner, newid, bucket)].set(
+                idx, mode="drop")
+            cnt = cnt + jnp.sum(winner.astype(jnp.int32))
+            own = jnp.take(tbl, cand, mode="clip")
+            ow = jnp.take(ktab, jnp.clip(own, 0, scratch_rows - 1), axis=0,
+                          mode="clip")
+            eq = (own >= 0) & jnp.all(ow == words, axis=1)
+            slot = jnp.where(active & eq, own, slot)
+            active = active & ~eq
+            return tbl, ktab, own_arr, cnt, slot, active, rnd + 1
 
-    st0 = (state_tbl, ktab_s,
-           jnp.full((bucket,), n, jnp.int32),
-           state_cnt,
-           jnp.full((n,), scratch_rows, jnp.int32), valid, jnp.int32(0))
-    tbl, ktab_s, new_owner, cnt, slot, active, _rnd = lax.while_loop(
-        cond, body, st0)
+        st0 = (state_tbl, ktab_s,
+               jnp.full((bucket,), n, jnp.int32),
+               state_cnt,
+               jnp.full((n,), scratch_rows, jnp.int32), valid, jnp.int32(0))
+        tbl, ktab_s, new_owner, cnt, slot, active, _rnd = lax.while_loop(
+            cond, body, st0)
 
-    placed = ~active & valid & (slot < bucket)
-    seg = jnp.where(placed, slot, bucket).astype(jnp.int32)
-    overflowed = jnp.sum((valid & (seg == bucket)).astype(jnp.int32))
-    # overflow keys claimed scratch ids ≥ bucket; scrub those probe slots
-    # (holes — hence the no-extend-after-overflow contract above)
-    tbl = jnp.where(tbl >= bucket, jnp.int32(-1), tbl)
-    return (seg, new_owner, overflowed, tbl, ktab_s[:bucket],
-            jnp.minimum(cnt, bucket))
+        placed = ~active & valid & (slot < bucket)
+        seg = jnp.where(placed, slot, bucket).astype(jnp.int32)
+        overflowed = jnp.sum((valid & (seg == bucket)).astype(jnp.int32))
+        # overflow keys claimed scratch ids ≥ bucket; scrub those probe slots
+        # (holes — hence the no-extend-after-overflow contract above)
+        tbl = jnp.where(tbl >= bucket, jnp.int32(-1), tbl)
+        return (seg, new_owner, overflowed, tbl, ktab_s[:bucket],
+                jnp.minimum(cnt, bucket))
 
 
 def slot_state_build(table, keys: Iterable[str], bucket: int,
@@ -458,43 +466,25 @@ def slot_state_build(table, keys: Iterable[str], bucket: int,
     state — the seeding counterpart of ``slot_segment_ids`` for callers
     that will keep extending (the serving layer's append path).  Counts
     as a slot *build* (bumps the build counter, sized adaptively from
-    the distinct sketch like the one-shot path); subsequent
-    ``slot_ids_extend`` calls bump the *extend* counter instead — the
-    acceptance spies diff both.  Returns ``(seg, owner, overflowed,
-    state)`` with ``owner`` already table-global (a fresh build's batch
-    IS the table)."""
+    the distinct sketch like the one-shot path); the serving layer
+    counts the extensions that follow (``ServeStats.slot_extends``).
+    Returns ``(seg, owner, overflowed, state)`` with ``owner`` already
+    table-global (a fresh build's batch IS the table)."""
     keys = tuple(keys)
     global _SLOT_BUILDS
     _SLOT_BUILDS += 1
-    words = key_words_for(table.columns[k] for k in keys)
-    mask = table.mask()
-    if expand is None:
-        expand = EXPAND
-        if (adaptive_enabled()
-                and not isinstance(words, jax.core.Tracer)
-                and not isinstance(mask, jax.core.Tracer)):
-            expand = adaptive_expand(distinct_count_sketch(table, keys),
-                                     bucket)
-    state = fresh_slot_state(words.shape[1], bucket, expand)
-    seg, owner, overflowed, state = slot_ids_extend(words, mask, state)
-    return seg, owner, overflowed, state
-
-
-_SLOT_EXTENDS = 0
-
-
-def slot_extend_count() -> int:
-    """Number of incremental ``slot_ids_extend`` calls made on behalf of
-    a Table append (the serving layer bumps it) since import — the
-    acceptance test asserts appends extend instead of rebuilding by
-    diffing this against ``slot_build_count``."""
-    return _SLOT_EXTENDS
-
-
-def note_slot_extend() -> None:
-    """Bump the extend counter (serving-layer append path)."""
-    global _SLOT_EXTENDS
-    _SLOT_EXTENDS += 1
+    with jax.named_scope("keyslot.build"):
+        words = key_words_for(table.columns[k] for k in keys)
+        mask = table.mask()
+        if expand is None:
+            expand = EXPAND
+            if (adaptive_enabled()
+                    and not isinstance(words, jax.core.Tracer)
+                    and not isinstance(mask, jax.core.Tracer)):
+                expand = adaptive_expand(
+                    distinct_count_sketch(table, keys), bucket)
+        state = fresh_slot_state(words.shape[1], bucket, expand)
+        return _slot_extend(words, mask, state, "keyslot.build")
 
 
 #: build-side probe-table expansion for ``build_probe``: the table holds
@@ -580,32 +570,33 @@ def build_probe(build_words: jax.Array, build_valid: jax.Array,
         bcond, bbody,
         (jnp.full((m,), nb, jnp.int32), bvalid, jnp.int32(0)))
 
-    hp = _hash_words(probe_words)
+    with jax.named_scope("keyslot.probe"):
+        hp = _hash_words(probe_words)
 
-    def pcond(st):
-        _ridx, _found, active, rnd = st
-        return (rnd < m) & jnp.any(active)
+        def pcond(st):
+            _ridx, _found, active, rnd = st
+            return (rnd < m) & jnp.any(active)
 
-    def pbody(st):
-        ridx, found, active, rnd = st
-        p = rnd.astype(jnp.uint32)
-        cand = ((hp + (p * (p + 1)) // 2) & mask).astype(jnp.int32)
-        own = jnp.take(tbl, cand, mode="clip")
-        empty = own >= nb
-        ow = jnp.take(build_words, jnp.clip(own, 0, nb - 1), axis=0,
-                      mode="clip")
-        eq = ~empty & jnp.all(ow == probe_words, axis=1)
-        hit = active & eq
-        ridx = jnp.where(hit, own, ridx)
-        found = found | hit
-        active = active & ~eq & ~empty
-        return ridx, found, active, rnd + 1
+        def pbody(st):
+            ridx, found, active, rnd = st
+            p = rnd.astype(jnp.uint32)
+            cand = ((hp + (p * (p + 1)) // 2) & mask).astype(jnp.int32)
+            own = jnp.take(tbl, cand, mode="clip")
+            empty = own >= nb
+            ow = jnp.take(build_words, jnp.clip(own, 0, nb - 1), axis=0,
+                          mode="clip")
+            eq = ~empty & jnp.all(ow == probe_words, axis=1)
+            hit = active & eq
+            ridx = jnp.where(hit, own, ridx)
+            found = found | hit
+            active = active & ~eq & ~empty
+            return ridx, found, active, rnd + 1
 
-    ridx, found, _a, _r = lax.while_loop(
-        pcond, pbody,
-        (jnp.full((npr,), nb, jnp.int32), jnp.zeros((npr,), bool),
-         pvalid, jnp.int32(0)))
-    return ridx, found
+        ridx, found, _a, _r = lax.while_loop(
+            pcond, pbody,
+            (jnp.full((npr,), nb, jnp.int32), jnp.zeros((npr,), bool),
+             pvalid, jnp.int32(0)))
+        return ridx, found
 
 
 # ---------------------------------------------------------------------------

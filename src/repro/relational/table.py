@@ -137,9 +137,11 @@ class Table:
         for k, d in zip(keys, desc):
             ops.append(_sort_key(self.columns[k], d, m))
         iota = lax.iota(jnp.int32, self.capacity)
-        res = lax.sort(tuple(ops) + (iota,), dimension=0, is_stable=True,
-                       num_keys=len(ops))
-        return self.take(res[-1])
+        with jax.named_scope("group_sort"):
+            res = lax.sort(tuple(ops) + (iota,), dimension=0,
+                           is_stable=True, num_keys=len(ops))
+        with jax.named_scope("sort_gather"):
+            return self.take(res[-1])
 
     def head(self, n: int) -> "Table":
         c = self.compress()
@@ -190,8 +192,9 @@ class Table:
         return tot
 
     def to_numpy(self) -> dict[str, np.ndarray]:
-        m = np.asarray(self.mask())
-        return {k: np.asarray(v)[m] for k, v in self.columns.items()}
+        with jax.profiler.TraceAnnotation("table.to_host"):
+            m = np.asarray(self.mask())
+            return {k: np.asarray(v)[m] for k, v in self.columns.items()}
 
 
 def _sort_key(col: jax.Array, descending: bool, valid: jax.Array) -> jax.Array:

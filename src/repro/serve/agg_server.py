@@ -57,22 +57,32 @@ each call runs a plain eager ``engine.execute``;
 ``REPRO_SERVE_GUARD=off`` disables the guard layer only (PR-6 serving
 behavior: caches and batching, raw exceptions).
 
+**Tracing**: the dispatcher's work is always spanned with
+``jax.profiler.TraceAnnotation`` (``agg.batch`` around one launch, with
+``agg.prepare``, ``agg.args``, ``agg.dispatch``, ``agg.await``,
+``agg.guard_scan``, ``agg.unbatch`` and ``agg.deliver`` inside it, and
+``agg.coalesce`` around the batching window), each tagged with the plan's
+name and the ids of its requests, so a profiler trace shows the host path
+on the device ops' clock.  With no profiler running a span costs about a
+microsecond.  See docs/serving.md, "Tracing a live server".
+
 See docs/serving.md for the cache-key / invalidation / batching contract.
 """
 from __future__ import annotations
 
 import copy
 import dataclasses
+import itertools
 import logging
 import math
 import threading
 import time
 import warnings
 from concurrent.futures import Future
-from contextlib import nullcontext
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 from dataclasses import replace as _dc_replace
-from typing import Any, Dict, Mapping, Optional, Tuple
+from typing import Any, Dict, Mapping, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -99,6 +109,13 @@ __all__ = ["AggServer", "ServeStats", "ServeRequest", "ServeResult",
            "serving_enabled", "guard_enabled"]
 
 _log = logging.getLogger(__name__)
+
+
+def _plan_name(plan: Plan) -> str:
+    """The name a plan's spans carry: the aggregate's name for an
+    ``AggCall``, else the plan node's type."""
+    agg = getattr(plan, "aggregate", None)
+    return getattr(agg, "name", None) or type(plan).__name__
 
 
 def serving_enabled() -> bool:
@@ -135,7 +152,10 @@ class ServeStats:
     while tracing), so it counts actual retraces, not calls.
     ``slot_extends`` counts incremental slot-table extensions (an append
     that reused the resident assignment instead of rebuilding);
-    ``folds`` counts resident micro-batch moment folds."""
+    ``folds`` counts resident micro-batch moment folds.
+    ``queue_wait_s`` sums, over every request the dispatcher took for a
+    launch, the seconds from its ``submit`` to that moment (shed
+    requests are not counted)."""
     requests: int = 0
     batches: int = 0
     traces: int = 0
@@ -149,6 +169,7 @@ class ServeStats:
     epoch_reads: int = 0    # lock-free published-epoch decodes
     checkpoints: int = 0    # durable checkpoints written
     restores: int = 0       # durable checkpoints restored
+    queue_wait_s: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -198,6 +219,15 @@ class ServeResult:
 #: bucket only forgives undershoot up to the next boundary
 _SKETCH_PAD = 1.3
 _SKETCH_SLACK = 16
+
+
+class _Queued(NamedTuple):
+    """A request waiting in the admission queue."""
+    params: Mapping[str, Any]
+    fut: Future
+    deadline: Optional[float]   # time.monotonic() past which it is shed
+    rid: int                    # the request's id in the spans
+    t_submit: float             # time.perf_counter() at submit
 
 
 @dataclass
@@ -286,9 +316,31 @@ class AggServer:
         self.guard_stats = GuardStats()
         #: keys whose absorbed backend failure was already logged
         self._logged_failures: set = set()
+        #: request ids, one per request in submission order
+        self._req_ids = itertools.count(1)
+        #: TraceMe metadata (plan, request ids) of the launch this
+        #: thread is running, carried by every span inside it
+        self._span_tags = threading.local()
+
+    # -- spans -------------------------------------------------------------
+    def _span(self, name: str):
+        return jax.profiler.TraceAnnotation(
+            name, **(getattr(self._span_tags, "v", None) or {}))
+
+    @contextmanager
+    def _batch_span(self, plan: Plan, ids):
+        """``agg.batch`` around one launch of the requests ``ids``; the
+        spans opened inside it on this thread carry the same tags."""
+        tags = {"plan": _plan_name(plan), "reqs": " ".join(map(str, ids))}
+        self._span_tags.v = tags
+        try:
+            with jax.profiler.TraceAnnotation("agg.batch", **tags):
+                yield
+        finally:
+            self._span_tags.v = None
 
     # -- stats plumbing ----------------------------------------------------
-    def _bump(self, name: str, k: int = 1) -> None:
+    def _bump(self, name: str, k: float = 1) -> None:
         with self._stats_lock:
             setattr(self.stats, name, getattr(self.stats, name) + k)
 
@@ -661,9 +713,11 @@ class AggServer:
         params = dict(params or {})
         if not serving_enabled():
             return execute(plan, self._catalog, params)
-        with self._lock:
-            return self._launch(self._prepare(plan),
-                                self._psig(params), [params])[0]
+        with self._batch_span(plan, [next(self._req_ids)]):
+            with self._lock:
+                with self._span("agg.prepare"):
+                    ent = self._prepare(plan)
+                return self._launch(ent, self._psig(params), [params])[0]
 
     # -- resident incremental aggregation ----------------------------------
     def snapshot(self, plan: Plan) -> Table:
@@ -947,6 +1001,7 @@ class AggServer:
             return fut
         key = (id(plan), self._psig(params))
         dl = None if deadline is None else time.monotonic() + float(deadline)
+        rid, t_sub = next(self._req_ids), time.perf_counter()
         with self._cv:
             if self._closed:
                 raise ServerClosed("AggServer is closed")
@@ -965,7 +1020,7 @@ class AggServer:
                 self._dispatcher.start()
             if key not in self._pending:
                 self._pending[key] = (plan, [])
-            self._pending[key][1].append((params, fut, dl))
+            self._pending[key][1].append(_Queued(params, fut, dl, rid, t_sub))
             self._cv.notify()
         return fut
 
@@ -979,9 +1034,9 @@ class AggServer:
             self._closed = True
             if not drain:
                 for _plan, reqs in self._pending.values():
-                    for _p, fut, _dl in reqs:
-                        if not fut.done():
-                            fut.set_exception(ServerClosed(
+                    for r in reqs:
+                        if not r.fut.done():
+                            r.fut.set_exception(ServerClosed(
                                 "AggServer closed without draining"))
                 self._pending.clear()
             self._cv.notify_all()
@@ -1030,7 +1085,8 @@ class AggServer:
                 time.sleep(0.25)     # deterministic queue-delay injection
             faults.fail("dispatcher_die")
             if self._batch_window > 0:
-                time.sleep(self._batch_window)   # let requests coalesce
+                with self._span("agg.coalesce"):
+                    time.sleep(self._batch_window)  # let requests coalesce
             while True:
                 with self._cv:
                     if not self._pending:
@@ -1043,6 +1099,9 @@ class AggServer:
                         del self._pending[key]
                 take = self._shed_expired(take)
                 if take:
+                    now = time.perf_counter()
+                    self._bump("queue_wait_s",
+                               sum(now - r.t_submit for r in take))
                     self._run_batch(plan, key[1], take)
 
     def _shed_expired(self, reqs):
@@ -1051,27 +1110,30 @@ class AggServer:
         have joined never pays for them."""
         now = time.monotonic()
         live = []
-        for params, fut, dl in reqs:
-            if dl is not None and now > dl:
+        for r in reqs:
+            if r.deadline is not None and now > r.deadline:
                 self._gbump("deadline_shed")
-                if not fut.done():
-                    fut.set_exception(DeadlineExceeded(
+                if not r.fut.done():
+                    r.fut.set_exception(DeadlineExceeded(
                         "request deadline passed while queued"))
             else:
-                live.append((params, fut, dl))
+                live.append(r)
         return live
 
     def _run_batch(self, plan: Plan, psig, reqs) -> None:
-        try:
-            with self._lock:
-                outs = self._launch(self._prepare(plan), psig,
-                                    [p for p, _f, _d in reqs])
-            for (_, fut, _), out in zip(reqs, outs):
-                fut.set_result(out)
-        except Exception as e:              # noqa: BLE001 — future carries it
-            for _, fut, _ in reqs:
-                if not fut.done():
-                    fut.set_exception(e)
+        with self._batch_span(plan, [r.rid for r in reqs]):
+            try:
+                with self._lock:
+                    with self._span("agg.prepare"):
+                        ent = self._prepare(plan)
+                    outs = self._launch(ent, psig, [r.params for r in reqs])
+                with self._span("agg.deliver"):
+                    for r, out in zip(reqs, outs):
+                        r.fut.set_result(out)
+            except Exception as e:          # noqa: BLE001 — future carries it
+                for r in reqs:
+                    if not r.fut.done():
+                        r.fut.set_exception(e)
 
     # -- plan preparation --------------------------------------------------
     @staticmethod
@@ -1223,7 +1285,6 @@ class AggServer:
                     [seg, jnp.full((t.capacity - seg.shape[0],),
                                    ent.bound, jnp.int32)])
             seg = seg.at[posj].set(segb)
-            keyslot.note_slot_extend()
             self._bump("slot_extends")
         occupied = jnp.arange(ent.bound, dtype=jnp.int32) < state.cnt
         arrs = tuple(jax.block_until_ready(a)
@@ -1303,14 +1364,17 @@ class AggServer:
         """(batch bucket, executable arguments) for one request bucket."""
         slots = ()
         if ent.slot_scan is not None:
-            got = self._slot_table(ent)   # may grow/disable the bound
+            with self._span("agg.prepare"):
+                got = self._slot_table(ent)   # may grow/disable the bound
             slots = got if got is not None else ()
         if not psig:
             return 1, (self._catalog, slots, {})
-        nb = 1 << (len(plist) - 1).bit_length()
-        padded = plist + [plist[-1]] * (nb - len(plist))  # pad lanes
-        pvec = {k: jnp.asarray(np.stack([np.asarray(p[k]) for p in padded]))
-                for k, _ in psig}
+        with self._span("agg.args"):
+            nb = 1 << (len(plist) - 1).bit_length()
+            padded = plist + [plist[-1]] * (nb - len(plist))  # pad lanes
+            pvec = {k: jnp.asarray(np.stack([np.asarray(p[k])
+                                             for p in padded]))
+                    for k, _ in psig}
         return nb, (self._catalog, slots, pvec)
 
     def _launch_bucket(self, ent: _PlanEntry, psig, plist,
@@ -1324,11 +1388,13 @@ class AggServer:
             self._gbump("degraded_launches")
         if not degraded:
             faults.fail("backend_exc")
-        out = fn(*args)
+        with self._span("agg.dispatch"):     # enqueue, or trace + compile
+            out = fn(*args)
         if not psig:
             return [out] * n
-        return [jax.tree_util.tree_map(lambda a, i=i: a[i], out)
-                for i in range(n)]    # padded lanes dropped
+        with self._span("agg.unbatch"):
+            return [jax.tree_util.tree_map(lambda a, i=i: a[i], out)
+                    for i in range(n)]    # padded lanes dropped
 
     def compiled_text(self, plan: Plan,
                       params: Optional[Mapping[str, Any]] = None) -> str:
@@ -1404,14 +1470,19 @@ class AggServer:
                     raise BackendFailure(
                         "kernel backend failed and the degraded (jnp) "
                         "fallback failed too") from e2
+            # the scan below blocks on the device anyway: waiting first
+            # splits the device's time from the scan's host work
+            with self._span("agg.await"):
+                jax.block_until_ready(outs)
             # poison scan: O(num_segments) per distinct result Table
             # (parameterless batches share one object — scan it once)
             seen: Dict[int, bool] = {}
             poisoned = False
-            for out in outs:
-                if id(out) not in seen:
-                    seen[id(out)] = is_poisoned(out)
-                poisoned = poisoned or seen[id(out)]
+            with self._span("agg.guard_scan"):
+                for out in outs:
+                    if id(out) not in seen:
+                        seen[id(out)] = is_poisoned(out)
+                    poisoned = poisoned or seen[id(out)]
             if not poisoned:
                 return outs
             self._gbump("poisoned")

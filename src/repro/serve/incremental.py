@@ -327,12 +327,14 @@ class ResidentAgg:
             cols.append(jnp.stack(rows))
         return jnp.concatenate([fused_b[:, :4], jnp.stack(cols)], axis=1)
 
+    @jax.named_scope("resident.fold")
     def _fold_math(self, vals_b, seg, pos, moments, owner, new_owner,
                    payloads, pvs, *, backend):
         """The pure-array local fold: batch fused pass → globalize →
         fold → payload/owner merges.  Shapes fix everything else, so the
         jit wrapper retraces only when the batch size or the resident
-        bucket changes."""
+        bucket changes.  Its device ops are named ``resident.fold`` in a
+        profiler trace."""
         nb = vals_b.shape[0]
         ns = moments.shape[2]
         bvalid = jnp.ones((nb,), bool)

@@ -3,7 +3,9 @@ TPU v5e — no chip needed, the TPU compiler is installed.  Interpret-mode
 tests cannot see what only the chip's compiler refuses: scalar-prefetch
 maps that overflow SMEM, operands padded past HBM, a Mosaic kernel GSPMD
 cannot partition.  Each test asserts the compiled program holds the
-kernel (``tpu_custom_call``).
+kernel (``tpu_custom_call``), under the name its ``pallas_call`` gives
+it, and the sorted route keeps its named scopes in the optimized HLO's
+metadata, where the profiler reads them.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library at a time, so every pytest worker
@@ -12,6 +14,7 @@ must collect these tests and only the one running them loads it.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +28,7 @@ from repro.kernels.segment_agg import (fused_segment_agg,
                                        normalize_moments)
 from repro.launch.sharded_agg import sharded_fused_segment_agg
 from repro.relational import Table, execute, keyslot
+from repro.relational.engine import segment_ids_for
 from repro.relational.group_bound import resolve_group_bound
 from repro.relational.plan import GroupAgg, Scan
 
@@ -69,6 +73,7 @@ def test_sorted_pruned_kernel_q21_shape_splits(one_chip):
                                           assume_sorted=True),
         *_args(LINEITEM_SF10, 1, one_chip))
     assert txt.count("tpu_custom_call") >= 4      # one launch per row range
+    assert "segment_agg_sorted" in txt
 
 
 def test_unsorted_single_tile_kernel_60m(one_chip):
@@ -77,6 +82,23 @@ def test_unsorted_single_tile_kernel_60m(one_chip):
                                           layout="unsorted"),
         *_args(LINEITEM_SF10, 3, one_chip))
     assert "tpu_custom_call" in txt
+    assert "segment_agg_unsorted" in txt
+
+
+def test_sorted_route_names_its_sort_and_gathers(one_chip):
+    # the group sort and the gathers that apply its permutation keep
+    # their scopes through optimization (a 64k-row table: the stable
+    # sort costs the TPU compiler most of a minute at any size)
+    n = 1 << 16
+    t = Table({"k": jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip),
+               "q": jax.ShapeDtypeStruct((n,), jnp.float32,
+                                         sharding=one_chip)},
+              jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=one_chip))
+    txt = _compiled_text(
+        lambda t: segment_ids_for(t, ("k",), (1 << 12) + 1), t)
+    names = set(re.findall(r'op_name="([^"]*)"', txt))
+    assert any(re.search(r"\bgroup_sort/sort$", n) for n in names)
+    assert any(re.search(r"\bsort_gather/.*gather$", n) for n in names)
 
 
 def test_index_moment_kernel_q2_partsupp(one_chip):
