@@ -18,12 +18,6 @@ operation is classed by its HLO opcode, the word before the operand list:
 * ``other``: everything else; on the sorted route most of it is the row
   gathers that apply the group sort's permutation (``Table.take``), which
   show as ``kind=kCustom`` fusions and carry no opcode of their own.
-
-Each op also keeps its innermost ``jax.named_scope`` (``Op.scope``, read
-from the HLO ``op_name`` the profiler records with the op's metadata:
-``chipbench/xplane_scopes.py``), and the trace keeps the program's own
-host spans (``Trace.spans``: the ``TraceAnnotation``s whose names start
-with ``agg.`` or ``table.``, their TraceMe metadata as fields).
 """
 from __future__ import annotations
 
@@ -32,13 +26,10 @@ import glob
 import os
 import re
 from dataclasses import dataclass, field
-from typing import Optional
 
 WINDOW_SPAN = "chipbench.window"
 DEVICE_PREFIX = "/device:"
 OPS_LINE = "XLA Ops"
-#: name prefixes of the program's host spans the reduction keeps
-SPAN_PREFIXES = ("agg.", "table.")
 
 
 @dataclass
@@ -48,26 +39,14 @@ class Op:
     end: int        # ns
     category: str   # sort | kernel | other
     device: int
-    scope: Optional[str] = None     # innermost named scope, if any
-
-
-@dataclass
-class Span:
-    """A host span of the program (``jax.profiler.TraceAnnotation``)."""
-    name: str
-    start: int      # ns
-    end: int        # ns
-    thread: int     # the host line (one per thread) it was recorded on
-    fields: dict = field(default_factory=dict)  # TraceMe metadata
 
 
 @dataclass
 class Trace:
-    """Device ops and program spans of one traced window (ns)."""
+    """Device ops of one traced window (ns)."""
     window: tuple
     ops: list = field(default_factory=list)
     devices: int = 0
-    spans: list = field(default_factory=list)
 
     @property
     def window_s(self) -> float:
@@ -218,25 +197,13 @@ def find_xplane(directory: str) -> str:
     return got[-1]
 
 
-def _span(ev, thread: int) -> Span:
-    """A host event as a ``Span``; the profiler has already moved its
-    TraceMe metadata (``name#k=v,...#``) into the event's stats."""
-    s = int(ev.start_ns)
-    return Span(ev.name, s, s + int(ev.duration_ns), thread,
-                {k: str(v) for k, v in ev.stats})
-
-
 def reduce(path: str) -> Trace:
     """Read one trace file into a ``Trace`` clipped to the window span
     (the whole trace when the span is absent)."""
     from jax.profiler import ProfileData
-
-    from chipbench.xplane_scopes import line_scopes
     pd = ProfileData.from_file(path)
-    scopes = line_scopes(path, OPS_LINE)
-    ops, spans, window = [], [], None
+    ops, window = [], None
     devices = []
-    thread = 0
     for plane in pd.planes:
         if plane.name.startswith(DEVICE_PREFIX):
             try:
@@ -247,25 +214,19 @@ def reduce(path: str) -> Trace:
                 if line.name != OPS_LINE:
                     continue
                 devices.append(dev)
-                named = scopes.get(plane.name, [])
-                for i, ev in enumerate(line.events):
+                for ev in line.events:
                     s = int(ev.start_ns)
                     e = s + int(ev.duration_ns)
-                    scope = named[i][1] if i < len(named) \
-                        and named[i][0] == ev.name else None
                     ops.append(Op(ev.name, s, e,
-                                  classify(ev.name), dev, scope))
+                                  classify(ev.name), dev))
             continue
         for line in plane.lines:
-            thread += 1
             for ev in line.events:
                 if ev.name == WINDOW_SPAN:
                     s = int(ev.start_ns)
                     window = (s, s + int(ev.duration_ns))
-                elif ev.name.startswith(SPAN_PREFIXES):
-                    spans.append(_span(ev, thread))
     if window is None:
         lo = min((o.start for o in ops), default=0)
         hi = max((o.end for o in ops), default=0)
         window = (lo, hi)
-    return Trace(window, ops, len(set(devices)), spans)
+    return Trace(window, ops, len(set(devices)))
