@@ -5,11 +5,11 @@ The first client is the fused arg-extremum acceptance bound: the grouped
 argmin/argmax lowering must issue NO row-capacity-sized gather — the
 kernel's index moment replaced the ``take(best, seg)`` hit-detection scan
 and the full-row candidate reduce, and the jnp fallback computes the index
-with a segmented ``associative_scan`` (slices, not gathers).  The group
-sort itself legitimately gathers full rows, so the spy compares against a
-no-arg baseline program rather than demanding zero: the arg-extremum must
-add nothing row-sized (``benchmarks/arg_gather_spy.py``, a tier-1 test,
-and a dedicated CI step all assert it).
+with a segmented ``associative_scan`` (slices, not gathers).  The spy
+compares against a no-arg baseline program rather than demanding zero,
+so the rest of the grouped program's row traffic is not its concern: the
+arg-extremum must add nothing row-sized (``benchmarks/arg_gather_spy.py``,
+a tier-1 test, and a dedicated CI step all assert it).
 
 The second client is the SORT census of the sort-free grouped route
 (hash-slotted segment ids, relational/keyslot.py): its acceptance bound
@@ -77,7 +77,7 @@ def sort_output_sizes(jaxpr) -> list[int]:
     """Largest flattened output element count of every ``sort`` equation
     in the (closed) jaxpr, recursing through call boundaries.  A variadic
     sort (``lax.sort`` with several operands, e.g. ``Table.sort_by``'s
-    keys + iota permutation) is ONE equation — its widest output is the
+    keys + carried columns) is ONE equation — its widest output is the
     size that matters, and fusing K argsorts into one variadic sort is
     visible as K equations collapsing to one."""
     sizes = []
@@ -108,6 +108,20 @@ def count_row_sized_gathers(jaxpr, n: int) -> int:
     but emitting a segment-sized result are intentionally not counted —
     output size is what the collective/memory cost scales with."""
     return sum(1 for s in gather_output_sizes(jaxpr) if s >= n)
+
+
+def count_row_sized_permuted(jaxpr, n: int) -> int:
+    """Number of row-set-sized arrays the program writes in a
+    data-dependent order: each row-sized gather output plus each output
+    of a row-sized sort (keys and payloads alike).  ``Table.sort_by``
+    carries its columns through the sort instead of gathering them by
+    its permutation, so this — not the gather count alone — is the row
+    traffic of the sorted group route to compare other routes with."""
+    sorted_outs = sum(
+        sum(1 for v in eqn.outvars
+            if math.prod(getattr(v.aval, "shape", ())) >= n)
+        for eqn in iter_eqns(jaxpr) if eqn.primitive.name == "sort")
+    return sorted_outs + count_row_sized_gathers(jaxpr, n)
 
 
 def row_census(jaxpr, n: int) -> dict[str, int]:

@@ -376,13 +376,16 @@ def test_ordered_agg_call_stays_sorted(monkeypatch):
 def test_sortfree_sort_census_tier1():
     """Tier-1 face of the CI spy: the sort-free lowering of the grouped
     bench programs contains ZERO row-sized sorts, the sorted route at
-    least one, and sort-free adds no row-sized gathers."""
+    least one and no row-sized gather (its columns ride through the
+    sort), and sort-free writes no more row-sized arrays in permuted
+    order (gathers, sort outputs) than the sorted route."""
     from benchmarks.sortfree_spy import sortfree_census
     counts = sortfree_census(2_000, 64, "jnp")
     for name, c in counts.items():
         assert c["row_sorts_sortfree"] == 0, (name, c)
         assert c["row_sorts_sorted"] >= 1, (name, c)
-        assert c["row_gathers_sortfree"] <= c["row_gathers_sorted"], \
+        assert c["row_gathers_sorted"] == 0, (name, c)
+        assert c["row_permuted_sortfree"] <= c["row_permuted_sorted"], \
             (name, c)
 
 
@@ -441,7 +444,8 @@ def test_kernel_unsorted_layout_skips_sorted_validation():
 
 
 def test_sort_by_is_one_variadic_sort():
-    from repro.analysis.jaxpr_spy import sort_output_sizes
+    from repro.analysis.jaxpr_spy import (count_row_sized_gathers,
+                                          sort_output_sizes)
     t = _table(1000, 30, invalid_every=4)
     for keys, desc in ((["k"], ()), (["k", "v"], [False, True]),
                        (["k", "v", "w"], [True, False, False])):
@@ -449,22 +453,66 @@ def test_sort_by_is_one_variadic_sort():
             lambda ks=keys, d=desc: tuple(
                 t.sort_by(ks, d).columns.values()))()
         assert len(sort_output_sizes(j)) == 1, keys
+        # 1-D columns ride through the sort: no gather by its permutation
+        assert count_row_sized_gathers(j, t.capacity) == 0, keys
 
 
-def test_sort_by_parity_with_lexsort_oracle():
-    rng = np.random.default_rng(4)
+def _sort_table(n, valid, seed=4):
+    """Columns of every kind the sorted route carries, with ties, NaN,
+    -0.0 and infinities among the floats, and one (n, 2) column that
+    takes the gathered fallback."""
+    rng = np.random.default_rng(seed)
+    floats = np.array([np.nan, -0.0, 0.0, np.inf, -np.inf, 1.5, -2.0,
+                       3.25], np.float32)
+    cols = {"i32": rng.integers(-20, 20, n).astype(np.int32),
+            "f32": rng.choice(floats, n),
+            "b": rng.random(n) < 0.5,
+            "i8": rng.integers(-3, 4, n).astype(np.int8),
+            "pair": rng.integers(0, 1000, (n, 2)).astype(np.int32)}
+    m = (rng.random(n) < 0.7) if valid else np.ones(n, bool)
+    t = Table({k: jnp.asarray(v) for k, v in cols.items()},
+              jnp.asarray(m) if valid else None)
+    return t, cols, m
+
+
+def _lexsort_oracle(cols, m, keys, desc):
+    sk = []
+    for k, d in zip(keys, desc):
+        c = cols[k].astype(np.int32) if cols[k].dtype == bool else cols[k]
+        c = -c if d else c
+        big = (np.inf if np.issubdtype(c.dtype, np.floating)
+               else np.iinfo(c.dtype).max)
+        sk.append(np.where(m, c, big))
+    return np.lexsort((np.arange(len(m)),) + tuple(sk[::-1]) + (~m,))
+
+
+@pytest.mark.parametrize("keys,desc,valid", [
+    (["i32"], [False], False),
+    (["f32"], [True], True),
+    (["b"], [False], True),
+    (["i8"], [True], False),
+    (["i8", "f32"], [True, False], False),
+    (["i32", "f32"], [False, True], True),
+    (["b", "i8", "f32"], [False, True, True], True),
+    (["f32", "i32", "b"], [True, True, False], False),
+])
+def test_sort_by_parity_with_lexsort_oracle(keys, desc, valid):
+    from jax.sharding import Mesh
     n = 1000
-    t = Table({"a": jnp.asarray(rng.integers(0, 50, n).astype(np.int32)),
-               "b": jnp.asarray(rng.uniform(-5, 5, n).astype(np.float32))},
-              jnp.asarray(rng.random(n) < 0.8))
-    st = t.sort_by(["a", "b"], [False, True])
-    m, a, b = (np.asarray(x) for x in (t.mask(), t.columns["a"],
-                                       t.columns["b"]))
-    order = np.lexsort((np.arange(n), np.where(m, -b, np.inf),
-                        np.where(m, a, np.iinfo(np.int32).max), ~m))
-    assert np.array_equal(np.asarray(st.columns["a"]), a[order])
-    assert np.array_equal(np.asarray(st.columns["b"]), b[order])
+    t, cols, m = _sort_table(n, valid)
+    mesh = Mesh(np.asarray(jax.devices()[:1]), ("data",))
+    t = t.declare_group_bound(64)
+    t = Table(t.columns, t.valid, t.group_bound, row_split=(mesh, "data"))
+    st = t.sort_by(keys, desc)
+    order = _lexsort_oracle(cols, m, keys, desc)
+    assert list(st.columns) == list(cols)
+    for k, c in cols.items():
+        got = np.asarray(st.columns[k])
+        assert got.dtype == c.dtype and got.shape == c.shape, k
+        assert np.array_equal(got.view(np.uint8), c[order].view(np.uint8)), k
     assert np.array_equal(np.asarray(st.mask()), m[order])
+    assert st.group_bound == t.group_bound == 128
+    assert st.row_split == (mesh, "data")
 
 
 def test_gather_join_duplicate_right_keys_deterministic():
