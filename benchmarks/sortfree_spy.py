@@ -1,5 +1,5 @@
 """Structural CI gate: the sort-free grouped lowering contains ZERO
-row-capacity-sized sort ops — and no more row-sized permuted writes.
+row-capacity-sized sort ops — and no new row-sized gathers.
 
 The sort-free route (relational/keyslot.py hash-slotted segment ids +
 ``layout='unsorted'`` kernel accumulation) exists to delete the group
@@ -13,13 +13,11 @@ program*, where it cannot silently regress:
    output under the sort-free route.  Segment-sized sorts would be legal
    (O(num_segments) work was never the problem); there are none of those
    either today, but only row scale is gated.
-2. **Permuted-write census** — the same programs write NO MORE
-   row-sized arrays in a data-dependent order (gather outputs, sort
-   outputs) than their sorted-route twins: the slotting probe loop's
-   owner/key lookups stay below the sort's own outputs, so the route
-   never trades the sort for equivalent row traffic.  The sorted twins
-   gather no rows at all (``Table.sort_by`` carries the columns as sort
-   payloads).
+2. **Gather census** — the same programs trace to NO MORE row-sized
+   gathers than their sorted-route twins, so the route never trades the
+   sort for equivalent gather traffic.  The sorted twins gather no rows
+   (``Table.sort_by`` carries every column through its sort), so the
+   slotting probe loop's owner/key lookups must gather none either.
 3. **Detector sanity** — the SAME programs with the route disabled
    (``REPRO_GROUPAGG_SORTFREE=off``) trace to at least one row-sized
    sort, proving the census would catch a regression to the sorted
@@ -36,7 +34,6 @@ import sys
 import jax
 
 from repro.analysis.jaxpr_spy import (count_row_sized_gathers,
-                                      count_row_sized_permuted,
                                       count_row_sized_sorts)
 from repro.relational import execute
 
@@ -98,8 +95,7 @@ def trace_agg_call(prog, env, cat, sortfree: bool, max_groups: int,
 def sortfree_census(n: int = 50_000, ngroups: int = 512,
                     backend: str = "jnp") -> dict[str, dict[str, int]]:
     """{program: {row_sorts_sortfree, row_sorts_sorted,
-    row_gathers_sortfree, row_gathers_sorted, row_permuted_sortfree,
-    row_permuted_sorted}} over the built-in
+    row_gathers_sortfree, row_gathers_sorted}} over the built-in
     GroupAgg battery and every fused grouped AggCall bench workload."""
     from benchmarks.group_agg import _catalog, _programs
     cat = _catalog(n, ngroups)
@@ -112,8 +108,6 @@ def sortfree_census(n: int = 50_000, ngroups: int = 512,
             "row_sorts_sorted": count_row_sized_sorts(sorted_, n),
             "row_gathers_sortfree": count_row_sized_gathers(free, n),
             "row_gathers_sorted": count_row_sized_gathers(sorted_, n),
-            "row_permuted_sortfree": count_row_sized_permuted(free, n),
-            "row_permuted_sorted": count_row_sized_permuted(sorted_, n),
         }
 
     census("groupagg_builtin",
@@ -143,17 +137,17 @@ def main() -> int:
                 failures.append(f"[{backend}] {name}: the sorted route "
                                 f"gathers rows by the sort's permutation "
                                 f"instead of carrying them: {c}")
-            if c["row_permuted_sortfree"] > c["row_permuted_sorted"]:
+            if c["row_gathers_sortfree"] > c["row_gathers_sorted"]:
                 failures.append(f"[{backend}] {name}: sort-free lowering "
-                                f"writes more row-sized arrays in permuted "
-                                f"order than the sorted route: {c}")
+                                f"adds row-sized gathers over the sorted "
+                                f"route: {c}")
     if failures:
         for f in failures:
             print("FAIL:", f, file=sys.stderr)
         return 1
     print("OK: sort-free grouped lowering contains zero row-capacity-sized "
-          "sorts and no more row-sized permuted writes (sorted route keeps "
-          "its sort, so the census would catch a regression)")
+          "sorts and no new row-sized gathers (sorted route keeps its "
+          "sort, so the census would catch a regression)")
     return 0
 
 

@@ -110,20 +110,6 @@ def count_row_sized_gathers(jaxpr, n: int) -> int:
     return sum(1 for s in gather_output_sizes(jaxpr) if s >= n)
 
 
-def count_row_sized_permuted(jaxpr, n: int) -> int:
-    """Number of row-set-sized arrays the program writes in a
-    data-dependent order: each row-sized gather output plus each output
-    of a row-sized sort (keys and payloads alike).  ``Table.sort_by``
-    carries its columns through the sort instead of gathering them by
-    its permutation, so this — not the gather count alone — is the row
-    traffic of the sorted group route to compare other routes with."""
-    sorted_outs = sum(
-        sum(1 for v in eqn.outvars
-            if math.prod(getattr(v.aval, "shape", ())) >= n)
-        for eqn in iter_eqns(jaxpr) if eqn.primitive.name == "sort")
-    return sorted_outs + count_row_sized_gathers(jaxpr, n)
-
-
 def row_census(jaxpr, n: int) -> dict[str, int]:
     """Row-sized sort AND gather counts in one walk — the combined
     acceptance census of the whole-plan-fusion clients: the hash-join /

@@ -133,8 +133,10 @@ class Table:
         not rebuilt from the transformed keys) and the mask from the
         sorted flag.  On a TPU v5e a carried 60M-row column adds 0.073 s
         to the sort, where gathering it by the sort's permutation takes
-        0.75 s.  Only a column of another shape is gathered, by an iota
-        payload's permutation (scope ``sort_gather``)."""
+        0.75 s.  XLA drops the payloads no consumer reads, so the sort
+        compiles and runs with the columns the plan uses, not every
+        column traced here.  Only a column of another shape is gathered,
+        by an iota payload's permutation (scope ``sort_gather``)."""
         keys = list(keys)
         desc = list(descending) or [False] * len(keys)
         m = self.mask()

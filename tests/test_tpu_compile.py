@@ -162,3 +162,25 @@ def test_served_groupagg_over_row_split_table_four_chips(mesh4,
     txt = _compiled_text(run, {"L": t}, slots)
     assert "tpu_custom_call" in txt
     assert "all-reduce" in txt
+
+
+def test_sorted_route_over_row_split_table_four_chips(mesh4):
+    """The group sort of a row-split LINEITEM-sized table: GSPMD takes
+    the one variadic sort that carries the columns, leaves no gather of
+    the row count to apply its permutation, and the program fits a
+    v5e's 16 GiB on each chip."""
+    rows = NamedSharding(mesh4, P("data"))
+    n = LINEITEM_SF10
+    t = Table({"k": jax.ShapeDtypeStruct((n,), jnp.int32, sharding=rows),
+               "q": jax.ShapeDtypeStruct((n,), jnp.float32, sharding=rows)},
+              jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=rows),
+              row_split=(mesh4, "data"))
+    compiled = jax.jit(
+        lambda t: segment_ids_for(t, ("k",), (1 << 12) + 1)).lower(t).compile()
+    txt = compiled.as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', txt))
+    assert any(re.search(r"\bgroup_sort/sort$", n) for n in names)
+    assert not re.search(rf"\[({n}|{n // 4})\]\S* gather\(", txt)
+    mem = compiled.memory_analysis()
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes) < 16 << 30
