@@ -304,6 +304,9 @@ def execute_agg_call(call: AggCall, catalog, env,
 #: sort-free grouped route only fires when every update is one of these
 #: ('last' is positional over the *iteration* order, so it stays sorted)
 _ORDER_INSENSITIVE_KINDS = ("sum", "prod", "min", "max", "arg_group")
+#: recognized update kinds whose result depends on the order of a
+#: group's rows: the attaining row of a tie, the last row
+_ORDER_SENSITIVE_KINDS = ("arg_group", "last")
 
 
 def _sortfree_eligible(call: AggCall, agg: CustomAggregate, mode: str,
@@ -348,7 +351,8 @@ def _grouped_call_setup(call: AggCall, t: Table, env,
     ``t``: segment range, row split, parameter bindings, and the route.
     ``env`` None (the parameters are not known yet) leaves the kernel
     bit of the route undecided."""
-    from repro.launch.sharded_agg import launch_rows, table_row_split
+    from repro.launch.sharded_agg import (launch_rows, shard_local_merge,
+                                          table_row_split)
     from repro.relational.engine import GroupRoute
     from repro.relational.group_bound import resolve_group_bound
     from repro.relational.keyslot import provided_slots
@@ -409,9 +413,17 @@ def _grouped_call_setup(call: AggCall, t: Table, env,
     kernel = None if env is None else (
         bool(kernel_updates)
         and resolve_backend(_segagg_backend()) != "jnp")
+    # a row-split input sorts shard by shard when the segments need no
+    # order across shards: no Eq.-6 ordering or sort keys, and a mode
+    # whose updates merge by group (the segmented scan walks rows)
+    merge = None
+    if (not sortfree and not call.ordered and not call.sort_keys
+            and mode in ("fused", "recognized")):
+        merge = shard_local_merge(t, call.group_keys, bound, shard_route)
     return _CallSetup(mode, nsegments, bound, shard_route, cached, rows,
                       outer_vals, updates_split,
-                      GroupRoute(sortfree, kernel, launch, nsegments))
+                      GroupRoute(sortfree, kernel, launch, nsegments,
+                                 merge))
 
 
 def grouped_call_route(call: AggCall, t: Table, env=None, *,
@@ -465,37 +477,57 @@ def grouped_agg_call(call: AggCall, catalog, env,
                                unplaced, bound,
                                {v: out[v] for v in agg.terminate_vars})
 
-    sort_keys = tuple(call.group_keys) + tuple(call.sort_keys)
-    sort_desc = (False,) * len(call.group_keys) + tuple(
-        call.sort_desc or (False,) * len(call.sort_keys))
-    st, seg, starts = segment_ids_for(
-        t.sort_by(sort_keys, sort_desc), call.group_keys,
-        num_segments=nsegments)
-    # note: sort_by in segment_ids_for re-sorts by group keys only (stable),
-    # preserving the intra-group order established above.
-    m = st.mask()
-    nseg = jnp.sum(starts.astype(jnp.int32))
-    overflow_ok = check_group_overflow(nseg, bound)
-    out_valid = jnp.arange(nsegments) < nseg
+    seg_map = None
+    if route.merge is not None:
+        # row-split input: one sort per shard by the group keys, partial
+        # groups merged across devices by key
+        from repro.launch.sharded_agg import shard_local_segments
+        ss = shard_local_segments(
+            t, call.group_keys, nsegments, bound, route.merge, *shard_route,
+            stable=any(u.kind in _ORDER_SENSITIVE_KINDS
+                       for u in agg.recognized))
+        st, seg, seg_map, m = ss.table, ss.seg, ss.seg_map, ss.table.mask()
+        overflow_ok = ss.guard()
+        out_valid = jnp.arange(nsegments) < ss.nseg
+        for name, e in call.param_binding:
+            if isinstance(e, Col):
+                rows[name] = st.columns[e.name]
+        cols.update(ss.keys)
+    else:
+        sort_keys = tuple(call.group_keys) + tuple(call.sort_keys)
+        sort_desc = (False,) * len(call.group_keys) + tuple(
+            call.sort_desc or (False,) * len(call.sort_keys))
+        st, seg, starts = segment_ids_for(
+            t.sort_by(sort_keys, sort_desc), call.group_keys,
+            num_segments=nsegments)
+        # note: sort_by in segment_ids_for re-sorts by group keys only
+        # (stable), preserving the intra-group order established above.
+        m = st.mask()
+        nseg = jnp.sum(starts.astype(jnp.int32))
+        overflow_ok = check_group_overflow(nseg, bound)
+        out_valid = jnp.arange(nsegments) < nseg
 
-    # re-bind fetch-derived params against the SORTED rows
-    for name, e in call.param_binding:
-        if isinstance(e, Col):
-            rows[name] = st.columns[e.name]
+        # re-bind fetch-derived params against the SORTED rows
+        for name, e in call.param_binding:
+            if isinstance(e, Col):
+                rows[name] = st.columns[e.name]
 
-    first_idx = jnp.where(starts, jnp.arange(cap), cap)
-    first_of_seg = jax.ops.segment_min(first_idx, seg,
-                                       num_segments=nsegments)
-    safe_first = jnp.clip(first_of_seg, 0, cap - 1)
-    for k in call.group_keys:
-        cols[k] = jnp.take(st.columns[k], safe_first)
+        first_idx = jnp.where(starts, jnp.arange(cap), cap)
+        first_of_seg = jax.ops.segment_min(first_idx, seg,
+                                           num_segments=nsegments)
+        safe_first = jnp.clip(first_of_seg, 0, cap - 1)
+        for k in call.group_keys:
+            cols[k] = jnp.take(st.columns[k], safe_first)
 
     if mode == "fused":
         out = _grouped_fused(agg, rows, outer_vals, m, seg, nsegments,
                              backend=_segagg_backend(),
                              require_kernel=call.mode == "fused",
-                             shard_route=shard_route)
+                             shard_route=shard_route, seg_map=seg_map)
     elif mode == "recognized":
+        if seg_map is not None:
+            from repro.launch.sharded_agg import global_segment_ids
+            seg = global_segment_ids(seg, seg_map, nsegments, *shard_route)
         out = _grouped_recognized(agg, rows, outer_vals, m, seg, nsegments)
     else:
         out = _grouped_scan(agg, rows, outer_vals, m, starts, seg,
@@ -603,7 +635,7 @@ def _split_kernel_updates(agg, outer_vals, col_env):
 def _grouped_fused(agg, rows, outer_vals, valid, seg, num_segments, backend="auto",
                    require_kernel=False, shard_route=None,
                    layout="sorted", sortfree_keys=None, table=None,
-                   updates_split=None):
+                   updates_split=None, seg_map=None):
     """Fused grouped aggregation: every recognized sum/min/max/arg-extremum
     update over a ≤32-bit floating field is batched into ONE fused
     segment-aggregate pass (each column carries its own guard mask, so
@@ -631,7 +663,10 @@ def _grouped_fused(agg, rows, outer_vals, valid, seg, num_segments, backend="aut
     each shard slots its own rows and the merge is key-aligned; ``seg``
     is unused and the return value becomes ``(out, (rep_rows, out_valid,
     unplaced))`` so the caller recovers representatives and validity
-    without global segment ids."""
+    without global segment ids.  ``seg_map``: ``seg`` holds shard-local
+    segment ids (``launch.sharded_agg.shard_local_segments``), which the
+    launcher maps onto global ones; the paths that need one global id
+    per row derive them once."""
     from repro.kernels.segment_agg import (ARGMAX_ROW, ARGMIN_ROW,
                                            fused_segment_agg,
                                            index_moment_ok)
@@ -639,6 +674,12 @@ def _grouped_fused(agg, rows, outer_vals, valid, seg, num_segments, backend="aut
     col_env = dict(outer_vals)
     col_env.update(rows)
     n = valid.shape[0]
+
+    def row_seg():
+        if seg_map is None:
+            return seg
+        from repro.launch.sharded_agg import global_segment_ids
+        return global_segment_ids(seg, seg_map, num_segments, *shard_route)
     # f32 row indices are exact below 2^24 PADDED rows (the same gate the
     # kernel validates); beyond that the arg-extremum keeps the kernel
     # key extremum but falls back to the legacy jnp pick
@@ -739,7 +780,8 @@ def _grouped_fused(agg, rows, outer_vals, valid, seg, num_segments, backend="aut
                 jnp.stack(masks, axis=1), num_segments, mesh=shard_route[0],
                 axis=shard_route[1], backend=backend,
                 moments=kernel_moments, assume_sorted=True,
-                payloads=tuple(payload_specs), layout=layout)
+                payloads=tuple(payload_specs), layout=layout,
+                seg_map=seg_map)
             fused, payload_picks = res if payload_specs else (res, ())
         else:
             from repro.reliability import faults as _faults
@@ -768,7 +810,7 @@ def _grouped_fused(agg, rows, outer_vals, valid, seg, num_segments, backend="aut
                         "min" if minimize else "max"](d)
                     masked = jnp.where(g, key.astype(d), worst)
                     _arg_group_select(u, outer_vals, col_env, g, masked,
-                                      best, seg, num_segments, out)
+                                      best, row_seg(), num_segments, out)
                 continue
             r = fused[c, {"sum": 0, "min": 2, "max": 3}[u.kind]].astype(d)
             if u.kind == "sum":
@@ -778,8 +820,9 @@ def _grouped_fused(agg, rows, outer_vals, valid, seg, num_segments, backend="aut
             else:
                 out[f] = jnp.maximum(outer_vals[f], r)
     if rest:
-        out.update(_grouped_recognized(agg, rows, outer_vals, valid, seg,
-                                       num_segments, updates=tuple(rest)))
+        out.update(_grouped_recognized(agg, rows, outer_vals, valid,
+                                       row_seg(), num_segments,
+                                       updates=tuple(rest)))
     if sortfree_keys is not None:
         # the caller pre-checked rest == [] and kernel_updates != [], so
         # sortfree_extras was always produced on this path

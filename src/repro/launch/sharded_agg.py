@@ -34,10 +34,18 @@ such tables through the sharded entry with no caller changes.  The split
 is per table: a replicated table in the same plan keeps the one-device
 launch.  ``REPRO_SEGAGG_SHARDED=off`` disables routing.
 
-Rows arrive sorted by segment (the grouped executors sort to derive
-segment ids), so every contiguous row shard is itself sorted — the band
-pruning of ``kernels/segment_agg.py`` applies per shard, and each shard's
-pruned grid only walks the segment tiles its band actually touches.
+On the sorted route each shard sorts its own rows: ``shard_local_segments``
+runs ``Table.sort_by`` per shard under ``shard_map`` and numbers the
+shard's segments, and the partial groups merge across shards by key,
+never by local segment number — by declared key ranges (a shift of each
+shard's segment ids by the groups before it) or by an all-gather of each
+shard's bound-sized key table slotted into one global table
+(``shard_local_merge`` picks from static metadata).  No op of the global
+row count crosses devices.  Every shard's rows are then sorted by
+segment, so the band pruning of ``kernels/segment_agg.py`` applies per
+shard, and each shard's pruned grid only walks the segment tiles its band
+actually touches.  Without a dense bound or declared ranges the group
+sort stays one GSPMD sort of every row.
 
 ``sharded_sortfree_segment_agg`` is the SORT-FREE counterpart: rows
 arrive in arbitrary order and each shard hash-slots its own rows
@@ -72,7 +80,7 @@ no changes here.
 """
 from __future__ import annotations
 
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -124,8 +132,8 @@ def table_row_split(t) -> Optional[tuple[Mesh, str]]:
     if not flags.enabled("REPRO_SEGAGG_SHARDED"):
         return None
     if t.row_split is not None:
-        mesh, axis = t.row_split
-        return t.row_split if mesh.shape[axis] > 1 else None
+        mesh, axis = t.row_split[:2]
+        return (mesh, axis) if mesh.shape[axis] > 1 else None
     return row_sharded_mesh(*t.columns.values(), t.valid)
 
 
@@ -134,7 +142,7 @@ def declare_row_split(t):
     a ``jit`` over it still launches per shard; ``t`` itself when there
     is nothing to declare."""
     route = table_row_split(t)
-    if route is None or route == t.row_split:
+    if route is None or t.row_split is not None:
         return t
     import dataclasses
     return dataclasses.replace(t, row_split=route)
@@ -147,6 +155,264 @@ def launch_rows(n: int, route: Optional[tuple[Mesh, str]]) -> int:
         return n
     mesh, axis = route
     return -(-n // mesh.shape[axis])
+
+
+def shard_local_merge(t, keys, bound: Optional[int],
+                      route: Optional[tuple[Mesh, str]]) -> Optional[str]:
+    """How the sorted route merges the partial groups of a row-split
+    table after each shard sorts its own rows (``shard_local_segments``),
+    decided from static metadata only:
+
+    * ``'ranges'``: the split is declared in ranges of keys that the
+      group keys are a prefix of (``Table.shard_rows(ordered_by=...)``),
+      so a group can only span the shards on either side of a boundary;
+      each shard's segments shift by the groups before it, whatever the
+      group count.
+    * ``'keys'``: a dense bound is declared and the shards' key tables
+      together hold no more entries than one shard has rows; the tables
+      are all-gathered and slotted into one global table.
+    * None: neither holds (or the rows do not divide evenly over the
+      shards); the group sort stays one GSPMD sort of every row."""
+    if route is None:
+        return None
+    mesh, axis = route
+    nshards = mesh.shape[axis]
+    if t.capacity % nshards:
+        return None
+    order = t.row_split[2] if t.row_split is not None \
+        and len(t.row_split) > 2 else ()
+    if tuple(keys) == tuple(order[:len(keys)]):
+        return "ranges"
+    if bound is not None and nshards * bound <= t.capacity // nshards:
+        return "keys"
+    return None
+
+
+class ShardSegments(NamedTuple):
+    """The shard-local group sort of a row-split table
+    (``shard_local_segments``)."""
+    #: the input table with each shard's rows sorted by the group keys
+    table: object
+    #: (N,) row-split segment ids: global ones (``'ranges'``), or each
+    #: shard's own in [0, L], L its overflow slot (``'keys'``)
+    seg: jax.Array
+    #: ``'keys'``: (shards × L,) row-split map from each shard's segment
+    #: ids to global ones; None for ``'ranges'``
+    seg_map: Optional[jax.Array]
+    #: group-key columns of the result, (num_segments,), replicated; the
+    #: groups fill the first ``nseg`` slots
+    keys: dict
+    nseg: jax.Array
+    #: groups beyond the dense bound (``'keys'``: entries left unslotted)
+    overflow: jax.Array
+    #: shard boundaries whose keys break the declared ranges (``'ranges'``)
+    disorder: jax.Array
+
+    def guard(self):
+        """The traced ``ok`` guard for ``poison_overflow`` (None when a
+        concrete result has nothing left to check); concrete failures
+        raise, as ``check_group_overflow`` does."""
+        from repro.relational.group_bound import GroupBoundOverflow
+        if isinstance(self.overflow, jax.core.Tracer):
+            return (self.overflow == 0) & (self.disorder == 0)
+        if int(self.disorder):
+            raise ValueError(
+                f"row-split table: {int(self.disorder)} shard boundaries "
+                f"break the key ranges the split declares (ordered_by)")
+        if int(self.overflow):
+            raise GroupBoundOverflow(
+                f"grouped aggregation over a row-split table: "
+                f"{int(self.overflow)} groups beyond the declared dense "
+                f"bound — raise max_groups or drop the declaration")
+        return None
+
+
+def shard_local_segments(t, keys, num_segments: int, bound: Optional[int],
+                         merge: str, mesh: Mesh, axis: str, *,
+                         stable: bool = True) -> ShardSegments:
+    """Group a row-split table shard by shard: under ``shard_map`` each
+    shard sorts its own rows by ``keys`` (``Table.sort_by``, scope
+    ``group_sort``, carrying the columns) and numbers its segments; the
+    partial groups are then aligned across shards by key (scope
+    ``shard_merge``) as ``merge`` says (``shard_local_merge``).  Nothing
+    of the global row count crosses devices: ``'ranges'`` all-gathers
+    each shard's group count and boundary keys and psums one
+    (num_segments,) key table; ``'keys'`` all-gathers each shard's
+    (bound,) key table.  Within a shard a group's rows keep their order,
+    and shards follow each other, so row order inside every group is the
+    table's.  ``stable=False``, for a caller whose aggregates ignore row
+    order within a group, drops that guarantee and the stable sort's
+    extra operand; the sort reads the keys back from its key operands
+    either way (``Table.sort_by``)."""
+    from repro.relational.engine import group_starts
+    from repro.relational.table import Table
+    keys = tuple(keys)
+    nshards = mesh.shape[axis]
+    n = t.capacity
+    shard_n = n // nshards
+    names = tuple(sorted(t.columns))
+    overflow_slot = num_segments - 1
+    width = min(overflow_slot, shard_n)   # 'keys': each shard's table
+
+    def local(valid, *cols):
+        st = Table(dict(zip(names, cols)), valid).sort_by(
+            keys, stable=stable, carry_keys=False)
+        m = st.mask()
+        starts = group_starts(st, keys)
+        lseg = jnp.cumsum(starts.astype(jnp.int32)) - 1
+        nloc = jnp.sum(starts.astype(jnp.int32))
+        with jax.named_scope("shard_merge"):
+            if merge == "ranges":
+                merged = _merge_ranges(st, m, starts, lseg, nloc, keys,
+                                       num_segments, bound, axis)
+            else:
+                merged = _merge_keys(st, m, starts, lseg, nloc, keys,
+                                     width, num_segments, axis, shard_n)
+        return (tuple(st.columns[c] for c in names), m) + merged
+
+    rows = P(axis)
+    out_specs = (tuple(rows for _ in names), rows, rows,
+                 {k: P() for k in keys}, P(), P(), P())
+    if merge == "keys":
+        out_specs += (rows,)
+    # jit: an eager call runs as one program, not op by op per shard
+    cols, valid, *merged = jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=(rows,) * (1 + len(names)),
+        out_specs=out_specs, check_vma=False))(
+            t.mask(), *(t.columns[c] for c in names))
+    seg, kcols, nseg, over, dis, *smap = merged
+    st = Table(dict(zip(names, cols)), valid, t.group_bound,
+               row_split=t.row_split)
+    return ShardSegments(st, seg, smap[0] if smap else None, kcols, nseg,
+                         over, dis)
+
+
+def global_segment_ids(seg, seg_map, num_segments: int, mesh: Mesh,
+                       axis: str) -> jax.Array:
+    """Per-row global segment ids from ``'keys'`` shard-local ones (a
+    gather of each shard's rows from its own map), for the paths that
+    need one id per row: jnp segment ops and the legacy arg tail."""
+    def local(s, sm):
+        return jnp.take(jnp.concatenate(
+            [sm, jnp.full((1,), num_segments - 1, jnp.int32)]), s)
+    return jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=(P(axis), P(axis)), out_specs=P(axis),
+        check_vma=False))(seg, seg_map)
+
+
+def _sort_lt(a, b):
+    """``a < b`` in the order of ``Table.sort_by``'s sort (a NaN after
+    every number, NaNs equal)."""
+    if jnp.issubdtype(a.dtype, jnp.floating):
+        return (a < b) | (~jnp.isnan(a) & jnp.isnan(b))
+    return a.astype(jnp.int32) < b.astype(jnp.int32) \
+        if a.dtype == jnp.bool_ else a < b
+
+
+def _sort_eq(a, b):
+    if jnp.issubdtype(a.dtype, jnp.floating):
+        return (a == b) | (jnp.isnan(a) & jnp.isnan(b))
+    return a == b
+
+
+def _to_bits(x):
+    if x.dtype == jnp.bool_:
+        return x.astype(jnp.uint8)
+    return lax.bitcast_convert_type(
+        x, jnp.dtype(f"uint{8 * x.dtype.itemsize}"))
+
+
+def _from_bits(u, dtype):
+    if dtype == jnp.bool_:
+        return u != 0
+    return lax.bitcast_convert_type(u, dtype)
+
+
+def _merge_ranges(st, m, starts, lseg, nloc, keys, num_segments, bound,
+                  axis):
+    """Declared key ranges: shard p's groups take the global slots after
+    those of the shards before it, less one where its first group is the
+    last group of the nearest non-empty shard before it."""
+    from repro.relational.engine import keys_equal
+    nval = jnp.sum(m.astype(jnp.int32))
+    last = jnp.maximum(nval - 1, 0)
+    first = [st.columns[k][0] for k in keys]
+    final = [st.columns[k][last] for k in keys]
+    g_nloc, g_nval, g_first, g_final = lax.all_gather(
+        (nloc, nval, first, final), axis)
+    p = jnp.arange(g_nloc.shape[0])
+    # the nearest non-empty shard before each shard (-1: none)
+    ne = jnp.where(g_nval > 0, p, -1)
+    prev = jnp.concatenate([jnp.full((1,), -1, ne.dtype),
+                            lax.cummax(ne)[:-1]])
+    follows = (g_nval > 0) & (prev >= 0)
+    q = jnp.maximum(prev, 0)
+    a = [f[q] for f in g_final]     # the previous shard's last key
+    joined = follows & jnp.all(jnp.stack(
+        [keys_equal(x, y) for x, y in zip(a, g_first)]), axis=0)
+    le = jnp.ones_like(follows)
+    for x, y in reversed(list(zip(a, g_first))):
+        le = _sort_lt(x, y) | (_sort_eq(x, y) & le)
+    disorder = jnp.sum((follows & ~le).astype(jnp.int32))
+    offset = (jnp.cumsum(g_nloc) - g_nloc
+              - jnp.cumsum(joined.astype(g_nloc.dtype)))
+    me = lax.axis_index(axis)
+    overflow_slot = num_segments - 1
+    nseg = jnp.sum(g_nloc) - jnp.sum(joined.astype(g_nloc.dtype))
+    seg = jnp.where(m, jnp.minimum(lseg + offset[me], overflow_slot),
+                    overflow_slot).astype(jnp.int32)
+    # one writer per slot: a shard whose first group continues the
+    # previous shard's leaves that key to it.  A max over sorted ids
+    # (zero elsewhere) is a plain scatter to the TPU compiler, where a
+    # set with dropped ids would lower to a sort.
+    write = starts & ~(joined[me] & (lseg == 0))
+    kcols = {}
+    for k in keys:
+        c = st.columns[k]
+        bits = _to_bits(c)
+        tab = jnp.zeros((num_segments,), bits.dtype).at[seg].max(
+            jnp.where(write, bits, jnp.zeros((), bits.dtype)),
+            indices_are_sorted=True, mode="promise_in_bounds")
+        kcols[k] = _from_bits(lax.psum(tab, axis), c.dtype)
+    over = jnp.int32(0) if bound is None else \
+        jnp.maximum(nseg - bound, 0).astype(jnp.int32)
+    return seg, kcols, nseg, over, disorder
+
+
+def _merge_keys(st, m, starts, lseg, nloc, keys, width, num_segments, axis,
+                shard_n):
+    """Dense bound: each shard's (width,) key table is all-gathered and
+    every shard slots the same gathered set into one global table
+    (``keyslot.slot_ids_from_words``: replicated compute, no further
+    collective); a shard's segments map to the slots of their keys."""
+    from repro.relational.keyslot import key_words_for, slot_ids_from_words
+    bucket = num_segments - 1
+    # each segment's first row; a min over sorted ids stays a scatter
+    pos = jax.ops.segment_min(
+        jnp.where(m, jnp.arange(shard_n, dtype=jnp.int32), shard_n),
+        jnp.clip(lseg, 0, width), num_segments=width + 1,
+        indices_are_sorted=True)[:width]
+    occ = jnp.arange(width) < nloc
+    ktab = [jnp.take(st.columns[k], pos) for k in keys]
+    gk = [lax.all_gather(c, axis).reshape(-1) for c in ktab]
+    gocc = lax.all_gather(occ, axis).reshape(-1)
+    slot, _owner, occupied, unplaced = slot_ids_from_words(
+        key_words_for(gk), gocc, bucket)
+    me = lax.axis_index(axis)
+    smap = lax.dynamic_slice_in_dim(slot, me * width, width)
+    # each global slot's key from its first gathered entry (shard order
+    # is row order); a min-scatter, where slotting's own owner table
+    # (unused, so compiled away) would lower to a sort
+    total = gk[0].shape[0]
+    first = jnp.full((num_segments,), total, jnp.int32).at[slot].min(
+        jnp.arange(total, dtype=jnp.int32))
+    rep = jnp.minimum(first, total - 1)
+    kcols = {k: jnp.take(c, rep) for k, c in zip(keys, gk)}
+    nseg = jnp.sum(occupied.astype(jnp.int32))
+    lost = lax.psum(jnp.maximum(nloc - width, 0), axis)
+    seg = jnp.where(m, jnp.minimum(lseg, width), width).astype(jnp.int32)
+    return (seg, kcols, nseg, (lost + unplaced).astype(jnp.int32),
+            jnp.int32(0), smap)
 
 
 def _merge_moments(local: jax.Array, axis_name: str) -> jax.Array:
@@ -222,7 +488,8 @@ def sharded_fused_segment_agg(vals: jax.Array, segs: jax.Array,
                               block_segs: int | None = None,
                               moments=MOMENTS, prune: bool = True,
                               assume_sorted: bool = False,
-                              payloads=(), layout: str = "sorted"):
+                              payloads=(), layout: str = "sorted",
+                              seg_map: Optional[jax.Array] = None):
     """Row-sharded fused segmented aggregation over ``mesh.shape[axis]``
     devices: each shard runs ``fused_segment_agg`` on its contiguous row
     slice (full segment range), then the (C, R, num_segments) moment
@@ -256,12 +523,21 @@ def sharded_fused_segment_agg(vals: jax.Array, segs: jax.Array,
     associativity-reordered across shard boundaries, so they are
     bitwise-equal when the addends are exactly representable
     (integer-valued data, the tests' parity case) and within normal f32
-    rounding otherwise."""
+    rounding otherwise.
+
+    ``seg_map`` (``shard_local_segments``' ``'keys'`` merge) makes
+    ``segs`` each shard's own segment ids in [0, L], L its overflow
+    slot, with ``seg_map`` the (shards × L,) row-split map from them to
+    global ids: each shard's kernel walks L + 1 segments and its moments
+    move onto the global segments before the merge."""
     from repro.reliability import faults as _faults
     _faults.fail("shard_launch")
     vals, valid = _normalize(jnp.asarray(vals), jnp.asarray(valid))
     segs = jnp.asarray(segs).astype(jnp.int32)
     nshards = mesh.shape[axis]
+    if seg_map is not None and segs.shape[0] % nshards:
+        raise ValueError("shard-local segment ids need rows that divide "
+                         "evenly over the shards")
     num_cols = vals.shape[1]
     norm_moments = normalize_moments(moments, num_cols)
     indexed = has_index_moments(norm_moments)
@@ -294,12 +570,32 @@ def sharded_fused_segment_agg(vals: jax.Array, segs: jax.Array,
                     [a, jnp.zeros((n_p - a.shape[0],), a.dtype)])
             pv_flat.append(jax.device_put(a, sh))
 
-    def local(v, s, g, *pv):
-        out = fused_segment_agg(v, s, g, num_segments,
+    def local(v, s, g, *rest):
+        pv = rest if seg_map is None else rest[1:]
+        width = None if seg_map is None else rest[0].shape[0]
+        out = fused_segment_agg(v, s, g,
+                                num_segments if width is None else width + 1,
                                 block_rows=block_rows,
                                 block_segs=block_segs, backend=backend,
                                 moments=norm_moments, prune=prune,
                                 assume_sorted=True, layout=layout)
+        if width is not None:
+            sm_local = rest[0]
+            fills = jnp.asarray(_row_fills(norm_moments),
+                                jnp.float32).reshape(num_cols, -1, 1)
+            with jax.named_scope("shard_merge"):
+                # the local segment of each global one (width: none), so
+                # the moments move by a gather: a set-scatter with the
+                # unoccupied slots' shared target would lower to a sort
+                inv = jnp.full((num_segments,), width, jnp.int32).at[
+                    sm_local].min(jnp.arange(width, dtype=jnp.int32))
+                out = jnp.where(inv < width,
+                                jnp.take(out, jnp.minimum(inv, width - 1),
+                                         axis=2), fills)
+        with jax.named_scope("shard_merge"):
+            return _merge_local(out, pv)
+
+    def _merge_local(out, pv):
         if not indexed:
             return _merge_moments(out, axis), ()
         sm = lax.psum(out[:, 0], axis)
@@ -339,10 +635,12 @@ def sharded_fused_segment_agg(vals: jax.Array, segs: jax.Array,
 
     out_specs = (P(), tuple(tuple(P() for _ in pvs)
                             for _c, _m, pvs in payloads))
-    out, picks = jax.shard_map(
+    extra = [] if seg_map is None else [seg_map]
+    out, picks = jax.jit(jax.shard_map(
         local, mesh=mesh,
-        in_specs=(P(axis),) * (3 + len(pv_flat)),
-        out_specs=out_specs, check_vma=False)(vals, segs, valid, *pv_flat)
+        in_specs=(P(axis),) * (3 + len(extra) + len(pv_flat)),
+        out_specs=out_specs, check_vma=False))(vals, segs, valid, *extra,
+                                               *pv_flat)
     if check_runtime:
         is_sorted = (jnp.all(segs[1:] >= segs[:-1])
                      if segs.shape[0] > 1 else jnp.bool_(True))

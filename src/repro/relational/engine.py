@@ -345,24 +345,37 @@ def segment_ids_for(t: Table, keys: tuple[str, ...],
     reserves it), the legacy capacity-1 slot otherwise."""
     st = t.sort_by(keys)
     m = st.mask()
-    same = jnp.ones(st.capacity, dtype=bool)
-    for k in keys:
-        c = st.columns[k]
-        eq = c[1:] == c[:-1]
-        if jnp.issubdtype(c.dtype, jnp.floating):
-            # NaN keys group per bit pattern, as the hash-slotted route's
-            # canonical words do (the sort makes equal NaNs adjacent)
-            from .keyslot import canonical_key_words
-            nan = jnp.isnan(c[1:]) & jnp.isnan(c[:-1])
-            for w in canonical_key_words(c):
-                nan = nan & (w[1:] == w[:-1])
-            eq = eq | nan
-        same = same & jnp.concatenate([jnp.array([False]), eq])
-    starts = m & ~same
+    starts = group_starts(st, keys)
     seg = jnp.cumsum(starts.astype(jnp.int32)) - 1
     overflow = (st.capacity if num_segments is None else num_segments) - 1
     seg = jnp.where(m, seg, overflow)  # park invalid rows in the last seg
     return st, seg, starts
+
+
+def group_starts(st: Table, keys: tuple[str, ...]) -> jax.Array:
+    """The valid rows of ``st`` (sorted by ``keys``) that start a group:
+    the first, and each whose keys differ from the row before."""
+    m = st.mask()
+    same = jnp.ones(st.capacity, dtype=bool)
+    for k in keys:
+        c = st.columns[k]
+        eq = keys_equal(c[1:], c[:-1])
+        same = same & jnp.concatenate([jnp.array([False]), eq])
+    return m & ~same
+
+
+def keys_equal(a: jax.Array, b: jax.Array) -> jax.Array:
+    """Elementwise: the key values ``a`` and ``b`` fall in one group."""
+    eq = a == b
+    if jnp.issubdtype(a.dtype, jnp.floating):
+        # NaN keys group per bit pattern, as the hash-slotted route's
+        # canonical words do (the sort makes equal NaNs adjacent)
+        from .keyslot import canonical_key_words
+        nan = jnp.isnan(a) & jnp.isnan(b)
+        for wa, wb in zip(canonical_key_words(a), canonical_key_words(b)):
+            nan = nan & (wa == wb)
+        eq = eq | nan
+    return eq
 
 
 _SEG_OPS = {
@@ -412,11 +425,15 @@ class GroupRoute(NamedTuple):
     the fused segment-aggregate kernel (None when a call's parameters
     were not given).  ``rows``: rows one kernel launch walks (a shard's
     slice when the input is row-split).  ``num_segments``: the segment
-    range."""
+    range.  ``merge``: on a row-split input, how the group sort runs
+    shard by shard and its partial groups merge across devices
+    (``launch.sharded_agg.shard_local_merge``); None where the sort is
+    one sort of every row."""
     sortfree: bool
     kernel: Optional[bool]
     rows: int
     num_segments: int
+    merge: Optional[str] = None
 
     @property
     def name(self) -> str:
@@ -479,7 +496,8 @@ def _group_agg_setup(t: Table, keys: tuple[str, ...], aggs,
                      cached: Optional[bool] = None) -> _GroupAggSetup:
     """Every static decision of ``_group_agg``: backend, segment range,
     row split, which ops ride the fused kernel pass, and the route."""
-    from repro.launch.sharded_agg import launch_rows, table_row_split
+    from repro.launch.sharded_agg import (launch_rows, shard_local_merge,
+                                          table_row_split)
     from .group_bound import resolve_group_bound
     from .keyslot import provided_slots, sortfree_enabled
     backend = _groupagg_fused_backend()
@@ -543,9 +561,12 @@ def _group_agg_setup(t: Table, keys: tuple[str, ...], aggs,
                     backend, rows, nsegments)))
     from repro.kernels.segment_agg import resolve_backend
     kernel = bool(fused_aggs) and resolve_backend(backend) != "jnp"
+    merge = None if sortfree else shard_local_merge(t, keys, bound,
+                                                    shard_route)
     return _GroupAggSetup(backend, nsegments, bound, shard_route, cached,
                           fused_aggs, rest_aggs,
-                          GroupRoute(sortfree, kernel, rows, nsegments))
+                          GroupRoute(sortfree, kernel, rows, nsegments,
+                                     merge))
 
 
 def _group_agg(t: Table, keys: tuple[str, ...],
@@ -567,6 +588,7 @@ def _group_agg(t: Table, keys: tuple[str, ...],
         return sortfree_result(t, keys, rep, out_valid, unplaced, bound,
                                out)
 
+    seg_map = None
     if sortfree:
         st, m = t, t.mask()
         seg, owner, occupied, unplaced = slot_segment_ids(t, keys, bound)
@@ -575,6 +597,16 @@ def _group_agg(t: Table, keys: tuple[str, ...],
         # shared sortfree_result epilogue after the aggregates compute
         rep, out_valid = overflow_extended(owner, occupied, cap)
         layout = "unsorted"
+    elif route.merge is not None:
+        from repro.launch.sharded_agg import shard_local_segments
+        ss = shard_local_segments(
+            t, keys, nsegments, bound, route.merge, *shard_route,
+            stable=any(op in _ARG_OPS for _out, op, _col in aggs))
+        st, seg, seg_map, m = ss.table, ss.seg, ss.seg_map, ss.table.mask()
+        overflow_ok = ss.guard()
+        out_valid = jnp.arange(nsegments) < ss.nseg
+        cols.update(ss.keys)
+        layout = "sorted"
     else:
         st, seg, starts = segment_ids_for(t, keys, num_segments=nsegments)
         m = st.mask()
@@ -593,8 +625,11 @@ def _group_agg(t: Table, keys: tuple[str, ...],
     if fused_aggs:
         cols.update(_group_agg_fused(st, seg, m, nsegments, fused_aggs,
                                      backend, shard_route=shard_route,
-                                     layout=layout))
+                                     layout=layout, seg_map=seg_map))
     aggs = rest_aggs
+    if aggs and seg_map is not None:
+        from repro.launch.sharded_agg import global_segment_ids
+        seg = global_segment_ids(seg, seg_map, nsegments, *shard_route)
 
     for out, op, col in aggs:
         if op == "count":
@@ -645,7 +680,7 @@ def _group_agg(t: Table, keys: tuple[str, ...],
 def _group_agg_fused(st: Table, seg: jax.Array, m: jax.Array,
                      num_segments: int, fused_aggs, backend: str,
                      shard_route=None, layout: str = "sorted",
-                     sortfree_keys=None):
+                     sortfree_keys=None, seg_map=None):
     """Serve sum/count/min/max/mean/argmin/argmax GroupAgg ops from ONE
     fused segment-aggregate pass: each distinct value (or arg-extremum
     key) column is one kernel column; all requested moments come back
@@ -667,7 +702,8 @@ def _group_agg_fused(st: Table, seg: jax.Array, m: jax.Array,
     rows itself and merge key-aligned; ``seg`` is then unused and the
     return value becomes ``(cols, (rep_rows, out_valid, unplaced))`` so
     the caller can recover representatives/validity without global
-    segment ids."""
+    segment ids.  ``seg_map``: ``seg`` holds shard-local segment ids
+    (``launch.sharded_agg.shard_local_segments``)."""
     from repro.core.executors import _index_row_to_pick
     from repro.kernels.segment_agg import (ARGMAX_ROW, ARGMIN_ROW,
                                            fused_segment_agg)
@@ -728,7 +764,7 @@ def _group_agg_fused(st: Table, seg: jax.Array, m: jax.Array,
             vals, seg.astype(jnp.int32), m[:, None], num_segments,
             mesh=shard_route[0], axis=shard_route[1], backend=backend,
             moments=kernel_moments, assume_sorted=True,
-            payloads=tuple(payload_specs), layout=layout)
+            payloads=tuple(payload_specs), layout=layout, seg_map=seg_map)
         fused, payload_picks = res if payload_specs else (res, ())
     else:
         fused = fused_segment_agg(vals, seg.astype(jnp.int32), m[:, None],

@@ -41,12 +41,14 @@ class Table:
     #: served stale data.  Not part of traced state.
     version: int = field(default_factory=lambda: next(_VERSIONS),
                          compare=False)
-    #: (mesh, axis) the rows are split over (``shard_rows``): static
-    #: metadata that rides in the pytree treedef, so a traced table still
-    #: states its split and the grouped executors launch the kernel per
-    #: row shard under ``shard_map`` (GSPMD cannot partition a Mosaic
-    #: kernel).  Ops that keep the table's row axis propagate it; concat
-    #: and head drop it.
+    #: (mesh, axis) the rows are split over (``shard_rows``), or (mesh,
+    #: axis, keys) when the split is also declared to be in ranges of
+    #: ``keys``: static metadata that rides in the pytree treedef, so a
+    #: traced table still states its split and the grouped executors
+    #: launch the kernel per row shard under ``shard_map`` (GSPMD cannot
+    #: partition a Mosaic kernel).  Ops that keep the table's row axis
+    #: propagate it; ops that move rows (take, compress, sort_by) keep
+    #: only (mesh, axis); concat and head drop it.
     row_split: Optional[tuple] = None
 
     # -- pytree ---------------------------------------------------------------
@@ -110,7 +112,7 @@ class Table:
                 for k, v in self.columns.items()}
         base = jnp.take(self.mask(), idx, mode="clip")
         v = base if idx_valid is None else base & idx_valid
-        return Table(cols, v, self.group_bound, row_split=self.row_split)
+        return Table(cols, v, self.group_bound, row_split=self._split())
 
     def compress(self) -> "Table":
         """Stable-compact valid rows to the front (fixed capacity)."""
@@ -119,10 +121,12 @@ class Table:
         t = self.take(order)
         n = jnp.sum(m.astype(jnp.int32))
         return Table(t.columns, jnp.arange(self.capacity) < n,
-                     self.group_bound, row_split=self.row_split)
+                     self.group_bound, row_split=self._split())
 
-    def sort_by(self, keys: Iterable[str], descending: Iterable[bool] = ()) -> "Table":
-        """Stable multi-key sort; invalid rows sort last.
+    def sort_by(self, keys: Iterable[str], descending: Iterable[bool] = (),
+                *, stable: bool = True, carry_keys: bool = True) -> "Table":
+        """Multi-key sort, stable unless ``stable=False``; invalid rows
+        sort last.
 
         ONE variadic ``lax.sort`` (scope ``group_sort``): the validity
         flag (invalid-last) leads, the transformed key columns follow in
@@ -136,7 +140,14 @@ class Table:
         0.75 s.  XLA drops the payloads no consumer reads, so the sort
         compiles and runs with the columns the plan uses, not every
         column traced here.  Only a column of another shape is gathered,
-        by an iota payload's permutation (scope ``sort_gather``)."""
+        by an iota payload's permutation (scope ``sort_gather``).
+
+        Two operands fewer for a caller that needs neither: the TPU
+        compiler implements a stable sort with one more iota operand
+        (``stable=False`` drops it: rows of equal keys then come in no
+        set order), and ``carry_keys=False`` reads each ascending key
+        column back from its own sort operand, exact on the valid rows
+        (an invalid row then holds the sort's fill)."""
         keys = list(keys)
         desc = list(descending) or [False] * len(keys)
         m = self.mask()
@@ -144,21 +155,27 @@ class Table:
         for k, d in zip(keys, desc):
             ops.append(_sort_key(self.columns[k], d, m))
         n = self.capacity
-        carried = [k for k, v in self.columns.items() if v.shape == (n,)]
-        gathered = [k for k in self.columns if k not in carried]
+        from_key = {} if carry_keys else {
+            k: i + 1 for i, (k, d) in enumerate(zip(keys, desc)) if not d}
+        carried = [k for k, v in self.columns.items()
+                   if v.shape == (n,) and k not in from_key]
+        gathered = [k for k in self.columns
+                    if k not in carried and k not in from_key]
         payload = [self.columns[k] for k in carried]
         if gathered:
             payload.append(lax.iota(jnp.int32, n))
         with jax.named_scope("group_sort"):
             res = lax.sort(tuple(ops) + tuple(payload), dimension=0,
-                           is_stable=True, num_keys=len(ops))
+                           is_stable=stable, num_keys=len(ops))
         out = dict(zip(carried, res[len(ops):]))
+        out.update((k, res[i].astype(self.columns[k].dtype))
+                   for k, i in from_key.items())
         if gathered:
             with jax.named_scope("sort_gather"):
                 out.update((k, jnp.take(self.columns[k], res[-1], axis=0,
                                         mode="clip")) for k in gathered)
         return Table({k: out[k] for k in self.columns}, res[0] == 0,
-                     self.group_bound, row_split=self.row_split)
+                     self.group_bound, row_split=self._split())
 
     def head(self, n: int) -> "Table":
         c = self.compress()
@@ -182,19 +199,37 @@ class Table:
         return Table(dict(self.columns), self.valid,
                      bucket_group_bound(max_groups), row_split=self.row_split)
 
-    def shard_rows(self, mesh, axis: str = "data") -> "Table":
+    def shard_rows(self, mesh, axis: str = "data",
+                   ordered_by: Iterable[str] = ()) -> "Table":
         """Commit every column (and the validity mask) to a row sharding —
         ``PartitionSpec(axis)`` on dim 0 — over ``mesh``, and declare the
-        split (``row_split``).  The grouped fused-aggregation path
-        (``GroupAgg`` and grouped ``AggCall``) reads the declaration and
-        runs the segment-aggregate kernel per row shard with a
-        cross-device moment merge (``launch/sharded_agg.py``), eagerly
-        and under ``jit`` alike — no other caller changes needed."""
+        split (``row_split``).  The grouped paths (``GroupAgg`` and
+        grouped ``AggCall``) read the declaration, eagerly and under
+        ``jit`` alike — no other caller changes needed: each shard sorts
+        its own rows by the group keys and runs the segment-aggregate
+        kernel on them, and the partial groups merge across devices by
+        key (``launch/sharded_agg.py``).
+
+        ``ordered_by`` declares that the split is also one of key
+        ranges: every key tuple of a shard sorts at or before every key
+        tuple of the next, as a table loaded in key order and cut into
+        contiguous row ranges is (an MPP warehouse's range-distributed
+        fact table).  A grouping by a prefix of those keys then merges
+        only at the shard boundaries, whatever its group count.  The
+        declaration is checked at run time: a violated one raises
+        eagerly and poisons a traced result."""
         from jax.sharding import NamedSharding, PartitionSpec
         sh = NamedSharding(mesh, PartitionSpec(axis))
         cols = {k: jax.device_put(v, sh) for k, v in self.columns.items()}
+        order = tuple(ordered_by)
         return Table(cols, jax.device_put(self.mask(), sh),
-                     self.group_bound, row_split=(mesh, axis))
+                     self.group_bound,
+                     row_split=(mesh, axis) + ((order,) if order else ()))
+
+    def _split(self) -> Optional[tuple]:
+        """``row_split`` without the range declaration, for ops that move
+        rows."""
+        return None if self.row_split is None else self.row_split[:2]
 
     def materialize(self) -> "Table":
         """Force device materialization — models the cursor temp table."""

@@ -155,7 +155,9 @@ class ServeStats:
     ``folds`` counts resident micro-batch moment folds.
     ``queue_wait_s`` sums, over every request the dispatcher took for a
     launch, the seconds from its ``submit`` to that moment (shed
-    requests are not counted)."""
+    requests are not counted).  ``sharded_launches`` counts the launches
+    of plans whose grouped node sorts a row-split catalog table shard by
+    shard (``GroupRoute.merge``)."""
     requests: int = 0
     batches: int = 0
     traces: int = 0
@@ -170,6 +172,7 @@ class ServeStats:
     checkpoints: int = 0    # durable checkpoints written
     restores: int = 0       # durable checkpoints restored
     queue_wait_s: float = 0.0
+    sharded_launches: int = 0
 
 
 @dataclass(frozen=True)
@@ -243,6 +246,7 @@ class _PlanEntry:
     bound: Optional[int] = None      # validated bucket; None → no slots
     slot_scan: Optional[str] = None  # catalog table the slots align to
     inferred: bool = False           # bound came from the sketch (growable)
+    shard_local: bool = False        # group sort runs shard by shard
     execs: Dict[Any, Any] = field(default_factory=dict)
 
 
@@ -1179,6 +1183,9 @@ class AggServer:
                         ent.keys = keys
                         ent.bound = bound
                         ent.slot_scan = scan
+                ent.shard_local = grouped_route(
+                    ent.plan, t,
+                    cached=ent.slot_scan is not None).merge is not None
         self._plans[id(plan)] = ent
         return ent
 
@@ -1384,6 +1391,8 @@ class AggServer:
         fn = self._executable(ent, psig, nb, degraded)
         self._bump("requests", n)
         self._bump("batches")
+        if ent.shard_local:
+            self._bump("sharded_launches")
         if degraded:
             self._gbump("degraded_launches")
         if not degraded:

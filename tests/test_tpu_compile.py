@@ -164,23 +164,50 @@ def test_served_groupagg_over_row_split_table_four_chips(mesh4,
     assert "all-reduce" in txt
 
 
-def test_sorted_route_over_row_split_table_four_chips(mesh4):
-    """The group sort of a row-split LINEITEM-sized table: GSPMD takes
-    the one variadic sort that carries the columns, leaves no gather of
-    the row count to apply its permutation, and the program fits a
-    v5e's 16 GiB on each chip."""
+def test_sorted_route_over_row_split_table_four_chips(mesh4, monkeypatch):
+    """The sorted route over a row-split LINEITEM-sized table, shard by
+    shard: each chip sorts its own quarter of the rows (scope
+    ``group_sort``), no op sorts, gathers or all-gathers the table's row
+    count, every collective moves at most the segment range (scope
+    ``shard_merge``), and the program fits a v5e's 16 GiB on each chip.
+    One program holds both merges: by declared key ranges (a Q18-like
+    grouping, up to a quarter of the rows in groups) and by all-gathered
+    key tables (a Q21-like one under a 100k-group bound)."""
+    monkeypatch.setenv("REPRO_GROUPAGG_FUSED", "pallas")
+    monkeypatch.setenv("REPRO_GROUPAGG_SORTFREE", "off")
     rows = NamedSharding(mesh4, P("data"))
     n = LINEITEM_SF10
-    t = Table({"k": jax.ShapeDtypeStruct((n,), jnp.int32, sharding=rows),
-               "q": jax.ShapeDtypeStruct((n,), jnp.float32, sharding=rows)},
+    t = Table({c: jax.ShapeDtypeStruct((n,), d, sharding=rows)
+               for c, d in (("k", jnp.int32), ("s", jnp.int32),
+                            ("q", jnp.float32))},
               jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=rows),
-              row_split=(mesh4, "data"))
-    compiled = jax.jit(
-        lambda t: segment_ids_for(t, ("k",), (1 << 12) + 1)).lower(t).compile()
+              row_split=(mesh4, "data", ("k",)))
+    by_k = GroupAgg(Scan("L", ("k", "s", "q")), ("k",),
+                    (("qty", "sum", "q"),), max_groups=n // 4)
+    by_s = GroupAgg(Scan("L", ("k", "s", "q")), ("s",),
+                    (("qty", "sum", "q"),), max_groups=100_000)
+    nseg = max(resolve_group_bound(p.max_groups, n)[0] for p in (by_k,
+                                                                by_s))
+    compiled = jax.jit(lambda c: (execute(by_k, c).columns,
+                                  execute(by_s, c).columns)).lower(
+        {"L": t}).compile()
     txt = compiled.as_text()
     names = set(re.findall(r'op_name="([^"]*)"', txt))
-    assert any(re.search(r"\bgroup_sort/sort$", n) for n in names)
-    assert not re.search(rf"\[({n}|{n // 4})\]\S* gather\(", txt)
+    assert any(re.search(r"\bgroup_sort/sort$", m) for m in names)
+    assert any("shard_merge" in m for m in names)
+    # after partitioning no op holds the global row count; every sort
+    # (the group sorts, and the scatters XLA lowers to sorts) holds at
+    # most a shard's rows
+    assert f"[{n}]" not in txt
+    sort_dims = {int(d) for line in txt.splitlines() if " sort(" in line
+                 for d in re.findall(r"\[(\d+)\]", line.split(" sort(")[0])}
+    assert n // 4 in sort_dims and max(sort_dims) <= n // 4
+    for line in re.findall(r"= .*? (?:all-reduce|all-gather|all-to-all|"
+                           r"collective-permute|reduce-scatter)"
+                           r"(?:-start)?\(", txt):
+        dims = [int(d) for d in re.findall(r"\[([\d,]+)\]", line)
+                for d in d.split(",")]
+        assert max(dims, default=0) <= nseg, line
     mem = compiled.memory_analysis()
     assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             + mem.output_size_in_bytes) < 16 << 30
