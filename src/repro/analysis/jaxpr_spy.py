@@ -19,6 +19,12 @@ lower to the ``sort`` primitive, so ``count_row_sized_sorts`` pins "the
 sort stays deleted" structurally (``benchmarks/sortfree_spy.py``, a
 tier-1 test, and a CI step).
 
+The third client is the sorted route's group-key table
+(``engine.segment_keys``): one compaction sort replaced a scatter of
+one update per row, and ``count_row_sized_scatters`` pins "the
+scatter stays deleted" on one chip and shard by shard
+(``tests/test_segment_keys.py``).
+
 Counting is done on the CLOSED jaxpr, pre-optimization: every ``jnp.take``
 / advanced-index lowers to the ``gather`` primitive there, every
 ``jnp.argsort`` / ``lax.sort`` to the ``sort`` primitive, the counts are
@@ -108,6 +114,29 @@ def count_row_sized_gathers(jaxpr, n: int) -> int:
     but emitting a segment-sized result are intentionally not counted —
     output size is what the collective/memory cost scales with."""
     return sum(1 for s in gather_output_sizes(jaxpr) if s >= n)
+
+
+def scatter_update_sizes(jaxpr) -> list[int]:
+    """Flattened element count of the UPDATES operand of every scatter
+    equation (``scatter``, ``scatter-add``, ``scatter-min``, ...) in the
+    (closed) jaxpr, recursing through call boundaries."""
+    sizes = []
+    for eqn in iter_eqns(jaxpr):
+        if eqn.primitive.name.startswith("scatter"):
+            shape = getattr(eqn.invars[2].aval, "shape", ())
+            sizes.append(int(math.prod(shape)))
+    return sizes
+
+
+def count_row_sized_scatters(jaxpr, n: int) -> int:
+    """Number of scatter equations that take at least one update per row
+    of an ``n``-row set — the acceptance metric of the sorted route's
+    group-key table (``engine.segment_keys``): a compaction by one sort
+    leaves ZERO of these, where a ``segment_min`` of each segment's first
+    row, or a ``.at[seg].max`` of its key, takes one update per row (on
+    a TPU v5e about 12 ns a row, against 1-1.5 ns for a sort payload).
+    Scatters of segment-sized updates are not counted."""
+    return sum(1 for s in scatter_update_sizes(jaxpr) if s >= n)
 
 
 def row_census(jaxpr, n: int) -> dict[str, int]:

@@ -438,7 +438,7 @@ def grouped_agg_call(call: AggCall, catalog, env,
     agg: CustomAggregate = call.aggregate
     t = _engine.execute_for_agg(call.child, catalog, env,
                                 _agg_call_needed(call))
-    from repro.relational.engine import segment_ids_for
+    from repro.relational.engine import segment_ids_for, segment_keys
     from repro.relational.group_bound import (check_group_overflow,
                                               poison_overflow)
     from repro.relational.keyslot import (overflow_extended,
@@ -511,13 +511,8 @@ def grouped_agg_call(call: AggCall, catalog, env,
         for name, e in call.param_binding:
             if isinstance(e, Col):
                 rows[name] = st.columns[e.name]
-
-        first_idx = jnp.where(starts, jnp.arange(cap), cap)
-        first_of_seg = jax.ops.segment_min(first_idx, seg,
-                                           num_segments=nsegments)
-        safe_first = jnp.clip(first_of_seg, 0, cap - 1)
-        for k in call.group_keys:
-            cols[k] = jnp.take(st.columns[k], safe_first)
+        cols.update(segment_keys(st, starts, seg, call.group_keys,
+                                 nsegments))
 
     if mode == "fused":
         out = _grouped_fused(agg, rows, outer_vals, m, seg, nsegments,

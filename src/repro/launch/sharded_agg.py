@@ -40,8 +40,10 @@ shard's segments, and the partial groups merge across shards by key,
 never by local segment number — by declared key ranges (a shift of each
 shard's segment ids by the groups before it) or by an all-gather of each
 shard's bound-sized key table slotted into one global table
-(``shard_local_merge`` picks from static metadata).  No op of the global
-row count crosses devices.  Every shard's rows are then sorted by
+(``shard_local_merge`` picks from static metadata).  Either merge reads
+each shard's groups from one compaction sort of its group keys
+(``engine.segment_keys``), as the one-device route does.  No op of the
+global row count crosses devices.  Every shard's rows are then sorted by
 segment, so the band pruning of ``kernels/segment_agg.py`` applies per
 shard, and each shard's pruned grid only walks the segment tiles its band
 actually touches.  Without a dense bound or declared ranges the group
@@ -267,7 +269,7 @@ def shard_local_segments(t, keys, num_segments: int, bound: Optional[int],
                                        num_segments, bound, axis)
             else:
                 merged = _merge_keys(st, m, starts, lseg, nloc, keys,
-                                     width, num_segments, axis, shard_n)
+                                     width, num_segments, axis)
         return (tuple(st.columns[c] for c in names), m) + merged
 
     rows = P(axis)
@@ -333,7 +335,7 @@ def _merge_ranges(st, m, starts, lseg, nloc, keys, num_segments, bound,
     """Declared key ranges: shard p's groups take the global slots after
     those of the shards before it, less one where its first group is the
     last group of the nearest non-empty shard before it."""
-    from repro.relational.engine import keys_equal
+    from repro.relational.engine import keys_equal, segment_keys
     nval = jnp.sum(m.astype(jnp.int32))
     last = jnp.maximum(nval - 1, 0)
     first = [st.columns[k][0] for k in keys]
@@ -361,39 +363,39 @@ def _merge_ranges(st, m, starts, lseg, nloc, keys, num_segments, bound,
     nseg = jnp.sum(g_nloc) - jnp.sum(joined.astype(g_nloc.dtype))
     seg = jnp.where(m, jnp.minimum(lseg + offset[me], overflow_slot),
                     overflow_slot).astype(jnp.int32)
-    # one writer per slot: a shard whose first group continues the
-    # previous shard's leaves that key to it.  A max over sorted ids
-    # (zero elsewhere) is a plain scatter to the TPU compiler, where a
-    # set with dropped ids would lower to a sort.
-    write = starts & ~(joined[me] & (lseg == 0))
+    # this shard's groups in order, placed at its offset in a zero table
+    # the psum fills: one writer per slot, so a shard whose first group
+    # continues the previous shard's leaves that key to it.  What lands
+    # past the first num_segments slots is cut off (a start past them is
+    # clamped to them); only an overflow puts a group there, and the
+    # guard poisons that result.
+    width = min(st.capacity, num_segments)
+    j = jnp.arange(width)
+    write = (j < nloc) & ~(joined[me] & (j == 0))
     kcols = {}
-    for k in keys:
-        c = st.columns[k]
+    for k, c in segment_keys(st, starts, lseg, keys, width).items():
         bits = _to_bits(c)
-        tab = jnp.zeros((num_segments,), bits.dtype).at[seg].max(
-            jnp.where(write, bits, jnp.zeros((), bits.dtype)),
-            indices_are_sorted=True, mode="promise_in_bounds")
-        kcols[k] = _from_bits(lax.psum(tab, axis), c.dtype)
+        bits = jnp.where(write, bits, jnp.zeros((), bits.dtype))
+        tab = lax.dynamic_update_slice(
+            jnp.zeros((num_segments + width,), bits.dtype), bits,
+            (offset[me],))
+        kcols[k] = _from_bits(lax.psum(tab[:num_segments], axis), c.dtype)
     over = jnp.int32(0) if bound is None else \
         jnp.maximum(nseg - bound, 0).astype(jnp.int32)
     return seg, kcols, nseg, over, disorder
 
 
-def _merge_keys(st, m, starts, lseg, nloc, keys, width, num_segments, axis,
-                shard_n):
+def _merge_keys(st, m, starts, lseg, nloc, keys, width, num_segments, axis):
     """Dense bound: each shard's (width,) key table is all-gathered and
     every shard slots the same gathered set into one global table
     (``keyslot.slot_ids_from_words``: replicated compute, no further
     collective); a shard's segments map to the slots of their keys."""
+    from repro.relational.engine import segment_keys
     from repro.relational.keyslot import key_words_for, slot_ids_from_words
     bucket = num_segments - 1
-    # each segment's first row; a min over sorted ids stays a scatter
-    pos = jax.ops.segment_min(
-        jnp.where(m, jnp.arange(shard_n, dtype=jnp.int32), shard_n),
-        jnp.clip(lseg, 0, width), num_segments=width + 1,
-        indices_are_sorted=True)[:width]
+    # entries past this shard's groups are masked out of the slotting
     occ = jnp.arange(width) < nloc
-    ktab = [jnp.take(st.columns[k], pos) for k in keys]
+    ktab = list(segment_keys(st, starts, lseg, keys, width).values())
     gk = [lax.all_gather(c, axis).reshape(-1) for c in ktab]
     gocc = lax.all_gather(occ, axis).reshape(-1)
     slot, _owner, occupied, unplaced = slot_ids_from_words(

@@ -14,6 +14,7 @@ from typing import Any, Mapping, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro.core.loop_ir import eval_expr
 from repro.configs import flags
@@ -364,6 +365,39 @@ def group_starts(st: Table, keys: tuple[str, ...]) -> jax.Array:
     return m & ~same
 
 
+def segment_keys(st: Table, starts: jax.Array, seg: jax.Array,
+                 keys: tuple[str, ...], width: int) -> dict[str, jax.Array]:
+    """The key columns of groups ``0..width-1`` of ``st``, its rows
+    sorted by ``keys`` with ``starts`` marking each group's first row and
+    ``seg`` numbering the groups: one ``(width,)`` array per key, each
+    group's key bit for bit.
+
+    A stream compaction by ONE unstable ``lax.sort`` (scope
+    ``segment_keys``): the sort key is the group number on start rows
+    and ``width`` on every other row, so the start rows come first, in
+    group order, whatever the order among the rest; the key columns ride
+    as payloads in their own dtypes (NaN, -0.0 and bool keys stay
+    exact).  No op takes one element per row: on a TPU v5e a scatter or
+    gather of one element per row costs about 12 ns a row, a sort with
+    one payload 1-1.5 ns.  Entries past the last group hold the key of
+    ``st``'s last row (zero for an empty table), as a gather of each
+    segment's first row, clipped to the rows, read."""
+    cap = st.capacity
+    cols = [st.columns[k] for k in keys]
+    with jax.named_scope("segment_keys"):
+        ids = jnp.where(starts, seg, width).astype(jnp.int32)
+        res = lax.sort((ids, *cols), dimension=0, is_stable=False,
+                       num_keys=1)
+    pad = max(width - cap, 0)
+    ids = jnp.pad(res[0][:width], (0, pad), constant_values=width)
+    got = ids < width
+    out = {}
+    for k, c, r in zip(keys, cols, res[1:]):
+        fill = c[cap - 1] if cap else jnp.zeros((), c.dtype)
+        out[k] = jnp.where(got, jnp.pad(r[:width], (0, pad)), fill)
+    return out
+
+
 def keys_equal(a: jax.Array, b: jax.Array) -> jax.Array:
     """Elementwise: the key values ``a`` and ``b`` fall in one group."""
     eq = a == b
@@ -613,13 +647,7 @@ def _group_agg(t: Table, keys: tuple[str, ...],
         nseg = jnp.sum(starts.astype(jnp.int32))
         overflow_ok = check_group_overflow(nseg, bound)
         out_valid = jnp.arange(nsegments) < nseg
-        # representative key values: first row of each segment
-        first_idx = jnp.where(starts, jnp.arange(cap), cap)
-        first_of_seg = jax.ops.segment_min(first_idx, seg,
-                                           num_segments=nsegments)
-        for k in keys:
-            cols[k] = jnp.take(st.columns[k],
-                               jnp.clip(first_of_seg, 0, cap - 1))
+        cols.update(segment_keys(st, starts, seg, keys, nsegments))
         layout = "sorted"
 
     if fused_aggs:

@@ -109,6 +109,14 @@ def cases(rng):
     k_sorted = np.concatenate([ks, np.full(SHARD, ks[-1])]).astype(np.int32)
     out["absent"] = [(k, v, valid, {"keys": ((), 100)}),
                      (k_sorted, v, valid, {"ranges": (("k",), 100)})]
+    # shard 1 holds no valid row, and one group runs from the end of
+    # shard 0 across it into the start of shard 2
+    k = rng.integers(0, 80, N).astype(np.int32)
+    k[SHARD - 50:2 * SHARD + 50] = 40
+    k = np.sort(k)
+    valid = (np.arange(N) < SHARD) | (np.arange(N) >= 2 * SHARD)
+    out["empty_shard"] = [(k, v, valid, {"ranges": (("k",), 80),
+                                         "keys": ((), 80)})]
     # a third of the rows invalid, the first and last rows among them
     k = np.sort(rng.integers(0, 90, N)).astype(np.int32)
     valid = rng.random(N) > 0.33
@@ -176,6 +184,40 @@ def broken_ranges(mesh):
     return errors
 
 
+def overflow(mesh):
+    """More groups than the declared bound, most of them on shard 2
+    alone: under either merge an eager run raises and a traced result
+    comes back poisoned whole, every entry of every column its
+    sentinel."""
+    from repro.relational.group_bound import (GroupBoundOverflow,
+                                              poison_sentinel)
+    per_shard = [np.repeat(np.arange(lo, lo + m), -(-SHARD // m))[:SHARD]
+                 for lo, m in ((0, 10), (10, 10), (20, 180), (200, 10))]
+    k = np.concatenate(per_shard).astype(np.int32)
+    t = Table.from_columns(k=k, v=np.ones(N, np.float32),
+                           i=np.arange(N, dtype=np.int32))
+    env = {"tot": jnp.float32(0.0), "cnt": jnp.float32(0.0)}
+    errors, taken = [], []
+    for merge, order in (("ranges", ("k",)), ("keys", ())):
+        sh = t.shard_rows(mesh, "data", ordered_by=order)
+        for plan, e in ((loop_call(100), env), (group_agg(100), None)):
+            where = f"overflow/{merge}/{type(plan).__name__}"
+            taken.append(grouped_route(plan, sh, e).merge)
+            try:
+                execute(plan, {"T": sh}, e)
+                errors.append(f"{where}: the eager run did not raise")
+            except GroupBoundOverflow:
+                pass
+            got = jax.jit(lambda c, e: execute(plan, c, e))({"T": sh}, e)
+            for c, col in got.columns.items():
+                bad = np.asarray(poison_sentinel(col.dtype))
+                if not np.array_equal(np.asarray(col),
+                                      np.broadcast_to(bad, col.shape),
+                                      equal_nan=col.dtype.kind == "f"):
+                    errors.append(f"{where}: {c} is not poisoned whole")
+    return {"merges": taken, "errors": errors}
+
+
 def main():
     assert jax.device_count() == 4, jax.device_count()
     mesh = Mesh(np.asarray(jax.devices()), ("data",))
@@ -186,6 +228,7 @@ def main():
         out[name] = {"merges": [m for g in got for m in g["merges"]],
                      "errors": [e for g in got for e in g["errors"]]}
     out["one_shard"]["errors"] += broken_ranges(mesh)
+    out["overflow"] = overflow(mesh)
     print(json.dumps(out))
 
 

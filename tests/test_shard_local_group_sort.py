@@ -4,7 +4,9 @@ devices sorts its own rows, and the partial groups merge across devices
 by key.  The cases run in one subprocess with four host devices
 (``tests/_shard_local_cases.py``); each compares, bit for bit on sums and
 counts, the row-split run (eager and under ``jit``, a grouped cursor
-loop and a ``GroupAgg``) with the one-device run and a numpy reference.
+loop and a ``GroupAgg``) with the one-device run and a numpy reference,
+but ``overflow``, whose groups exceed the declared bound: there an eager
+run must raise and a traced one come back poisoned whole.
 
 The sort-free route is switched off in the subprocess: on the CPU its
 kernel-grid gate passes at every size, where on the chip the plans of
@@ -26,8 +28,10 @@ CASES = {
     "every_shard": ["keys"] * 2,
     "straddles": ["ranges"] * 2 + ["keys"] * 2,
     "absent": ["keys"] * 2 + ["ranges"] * 2,
+    "empty_shard": ["ranges"] * 2 + ["keys"] * 2,
     "invalid": ["ranges"] * 2 + ["keys"] * 2,
     "nan_key": ["keys"] * 2 + ["ranges"] * 2,
+    "overflow": ["ranges"] * 2 + ["keys"] * 2,
 }
 
 
